@@ -84,6 +84,8 @@ def main(argv=None) -> int:
                          "fidelity tiers, fig14 scale-out, fig15 stability "
                          "control): NVLink mesh vs TPU v5e ICI torus")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     names = args.only.split(",") if args.only else BENCHES
     all_checks, failed = [], []

@@ -73,15 +73,25 @@ def pool_partials(q, pool_k, pool_v, slot_req, slot_base, q_pos,
     acc_n = jnp.einsum("nKgs,nsKh->nKgh", p.astype(pool_v.dtype), pool_v,
                        preferred_element_type=f32)
 
-    # merge per-slot partials into per-request partials (segment LSE)
+    # merge per-slot partials into per-request partials (segment LSE).
+    # Slots are first sorted by (request, first position), so each
+    # request's sums run in block order: which slot an eviction and a
+    # reload put a block in must not change the rounding, or a bf16
+    # near-tie could flip a token across tiers.
     seg = jnp.where(slot_req >= 0, slot_req, b)              # b = trash row
-    m_r = jax.ops.segment_max(m_n, seg, num_segments=b + 1)[:b]
+    order = jnp.lexsort((slot_base, seg))
+    seg, m_n, l_n, acc_n = (jnp.take(x, order, axis=0)
+                            for x in (seg, m_n, l_n, acc_n))
+    m_r = jax.ops.segment_max(m_n, seg, num_segments=b + 1,
+                              indices_are_sorted=True)[:b]
     m_r = jnp.maximum(m_r, NEG_INF)                          # empty -> -inf-ish
     corr = jnp.exp(m_n - jnp.take(m_r, jnp.clip(seg, 0, b - 1), axis=0))
-    corr = jnp.where((slot_req >= 0)[:, None, None], corr, 0.0)
-    l_r = jax.ops.segment_sum(l_n * corr, seg, num_segments=b + 1)[:b]
+    corr = jnp.where((seg < b)[:, None, None], corr, 0.0)
+    l_r = jax.ops.segment_sum(l_n * corr, seg, num_segments=b + 1,
+                              indices_are_sorted=True)[:b]
     acc_r = jax.ops.segment_sum(acc_n * corr[..., None], seg,
-                                num_segments=b + 1)[:b]
+                                num_segments=b + 1,
+                                indices_are_sorted=True)[:b]
     return Partials(m=m_r, l=l_r, acc=acc_r)
 
 

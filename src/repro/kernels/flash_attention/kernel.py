@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -75,7 +77,7 @@ def flash_attention(q, k, v, *, sq: int, scale: Optional[float] = None,
                     sliding_window: Optional[int] = None,
                     attention_chunk: Optional[int] = None,
                     q_block: int = 128, kv_block: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B, gq*sq, hd);  k, v: (B, sk, hd).  Returns (B, gq*sq, hd)."""
     B, qrows, hd = q.shape
     sk = k.shape[1]
@@ -108,5 +110,5 @@ def flash_attention(q, k, v, *, sq: int, scale: Optional[float] = None,
             pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

@@ -15,10 +15,6 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention.kernel import flash_attention
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=(
     "sliding_window", "attention_chunk", "q_block", "kv_block", "interpret"))
 def mha(q, k, v, *, sliding_window: Optional[int] = None,
@@ -28,7 +24,6 @@ def mha(q, k, v, *, sliding_window: Optional[int] = None,
     b, sq, nq, hd = q.shape
     sk, nkv = k.shape[1], k.shape[2]
     gq = nq // nkv
-    interp = (not _on_tpu()) if interpret is None else interpret
 
     # fold: (b, sq, nkv, gq, hd) -> (b*nkv, gq*sq, hd)
     qf = q.reshape(b, sq, nkv, gq, hd).transpose(0, 2, 3, 1, 4)
@@ -38,6 +33,6 @@ def mha(q, k, v, *, sliding_window: Optional[int] = None,
 
     of = flash_attention(qf, kf, vf, sq=sq, sliding_window=sliding_window,
                          attention_chunk=attention_chunk, q_block=q_block,
-                         kv_block=kv_block, interpret=interp)
+                         kv_block=kv_block, interpret=interpret)
     o = of.reshape(b, nkv, gq, sq, hd).transpose(0, 3, 1, 2, 4)
     return o.reshape(b, sq, nq, hd)

@@ -25,12 +25,15 @@ Grids: (num_blocks_to_copy, chunks_per_block).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _copy_kernel(ids_ref, src_ref, dst_ref):
@@ -66,7 +69,7 @@ def _chunking(elems: int, chunk: int):
 
 
 def harvest_gather(src_pool, slot_ids, *, chunk: int = 512,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """src_pool: (n_slots, block_elems); slot_ids: (m,) int32
     -> (m, block_elems) staging buffer."""
     n_slots, elems = src_pool.shape
@@ -88,13 +91,13 @@ def harvest_gather(src_pool, slot_ids, *, chunk: int = 512,
         _copy_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, padded), src_pool.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(slot_ids.astype(jnp.int32), src_pool)
     return out[:, :elems] if padded != elems else out
 
 
 def harvest_copy(src_pool, dst_pool, src_ids, dst_ids, *, chunk: int = 512,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """Fused gather→scatter: dst_pool[dst_ids[i]] <- src_pool[src_ids[i]].
 
     One pallas_call, no dense staging buffer: the source BlockSpec chases
@@ -133,7 +136,7 @@ def harvest_copy(src_pool, dst_pool, src_ids, dst_ids, *, chunk: int = 512,
         # operand 3 = dst_pool (after the 2 scalar-prefetch id lists and
         # src_pool): aliasing it into the output preserves untouched slots
         input_output_aliases={3: 0},
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(src_ids.astype(jnp.int32), dst_ids.astype(jnp.int32), src_pool,
       dst_pool)
     return out[:, :elems] if padded != elems else out
@@ -152,52 +155,120 @@ FIDELITY_QMAX = {"int8": 127.0, "fp8": 448.0, "int4": 7.0}
 FIDELITY_QDTYPE = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn,
                    "int4": jnp.uint8}
 
+#: lane width of a TPU vector register; a block is viewed as (rows, 128)
+_LANES = 128
+#: blocks are padded to a multiple of 64 lane rows, so the f32 source is
+#: (8, 128)-tiled and int4's packed half (rows / 2) is a whole number of
+#: the (32, 128) tiles 8-bit planes use
+_ROW_ALIGN = 64
+
+
+def _padded_elems(elems: int) -> int:
+    """Elements per block after padding to whole aligned lane rows."""
+    align = _ROW_ALIGN * _LANES
+    return -(-elems // align) * align
+
 
 def _packed_width(elems: int, fidelity: str) -> int:
-    """Columns of the packed value plane for a block of ``elems`` weights
-    (int4 packs two nibbles per byte; a non-divisible tail pads)."""
-    return (elems + 1) // 2 if fidelity == "int4" else elems
+    """Columns of the packed value plane for a block of ``elems`` weights.
+    int4 packs element ``j`` of the padded block into the low nibble of
+    byte ``j`` and element ``j + padded/2`` into its high nibble — two
+    contiguous halves, no lane interleave."""
+    padded = _padded_elems(elems)
+    return padded // 2 if fidelity == "int4" else padded
+
+
+def _row_chunk(rows: int) -> int:
+    """Rows processed per loop step: bounded VMEM temporaries, and a
+    multiple of 32 so 8-bit stores stay tile-aligned."""
+    for c in (512, 256, 128, 64, 32):
+        if rows % c == 0:
+            return c
+    raise ValueError(f"block of {rows} lane rows is not 32-row aligned")
+
+
+def _quantize_rows(x, scale, fidelity):
+    x = x.astype(jnp.float32) / scale
+    if fidelity == "fp8":
+        return x.astype(jnp.float8_e4m3fn)
+    qmax = FIDELITY_QMAX[fidelity]
+    q = jnp.clip(jnp.round(x), -qmax, qmax).astype(jnp.int32)
+    return q.astype(jnp.int8) if fidelity == "int8" else q
+
+
+def _unpack_nibble(b):
+    """Sign-extend a two's-complement nibble held in an int32."""
+    b = b & 15
+    return b - 2 * (b & 8)
 
 
 def _quantize_kernel(ids_ref, src_ref, val_ref, scale_ref, *, fidelity):
-    """One grid step = one gathered block row: absmax scale, quantize,
-    pack — no dense full-precision staging of the batch.  The ``(None,
-    width)`` BlockSpecs squeeze the slot dim, so refs are 1-D here."""
-    row = src_ref[...].astype(jnp.float32).reshape(-1)
-    absmax = jnp.max(jnp.abs(row))
+    """One grid step = one gathered block, viewed as (rows, 128): a first
+    pass takes the absmax, a second quantizes and packs, both in row
+    chunks so no full-block temporary lives in VMEM."""
+    rows = src_ref.shape[0]
+    out_rows = val_ref.shape[0]
+    chunk = _row_chunk(out_rows)
+
+    def absmax_step(i, acc):
+        r = pl.multiple_of(i * chunk, chunk)
+        x = jnp.abs(src_ref[pl.ds(r, chunk), :].astype(jnp.float32))
+        return jnp.maximum(acc, jnp.max(x, axis=0, keepdims=True))
+
+    acc = jax.lax.fori_loop(0, rows // chunk, absmax_step,
+                            jnp.zeros((1, _LANES), jnp.float32))
+    absmax = jnp.max(acc)
     # all-zero blocks quantize to zeros with a unit scale instead of a NaN
     scale = jnp.where(absmax == 0.0, 1.0, absmax / FIDELITY_QMAX[fidelity])
     scale_ref[...] = jnp.full(scale_ref.shape, scale, dtype=jnp.float32)
-    x = row / scale
-    if fidelity == "int8":
-        out = jnp.clip(jnp.round(x), -127, 127).astype(jnp.int8)
-    elif fidelity == "fp8":
-        out = x.astype(jnp.float8_e4m3fn)
-    else:  # int4: two's-complement nibbles, two weights per byte
-        q = jnp.clip(jnp.round(x), -7, 7).astype(jnp.int32)
-        q = q.reshape(-1, 2)
-        out = ((q[:, 0] & 15) | ((q[:, 1] & 15) << 4)).astype(jnp.uint8)
-    val_ref[...] = out.reshape(val_ref.shape)
+
+    def pack_step(i, carry):
+        r = pl.multiple_of(i * chunk, chunk)
+        q = _quantize_rows(src_ref[pl.ds(r, chunk), :], scale, fidelity)
+        if fidelity == "int4":
+            hi = _quantize_rows(src_ref[pl.ds(out_rows + r, chunk), :],
+                                scale, fidelity)
+            q = ((q & 15) | ((hi & 15) << 4)).astype(jnp.uint8)
+        val_ref[pl.ds(r, chunk), :] = q
+        return carry
+
+    jax.lax.fori_loop(0, out_rows // chunk, pack_step, 0)
 
 
 def _dequantize_kernel(ids_ref, val_ref, scale_ref, dst_in_ref, dst_ref,
                        *, fidelity):
-    scale = scale_ref[...].reshape(-1)[0]
-    q = val_ref[...].reshape(-1)
-    if fidelity == "int4":
-        b = q.astype(jnp.int32)
-        lo = b & 15
-        lo = lo - 2 * (lo & 8)          # sign-extend the nibble
-        hi = (b >> 4) & 15
-        hi = hi - 2 * (hi & 8)
-        x = jnp.stack([lo, hi], axis=-1).reshape(-1).astype(jnp.float32)
-    else:
-        x = q.astype(jnp.float32)
-    dst_ref[...] = (x * scale).reshape(dst_ref.shape).astype(dst_ref.dtype)
+    scale = scale_ref[0, 0]
+    in_rows = val_ref.shape[0]
+    chunk = _row_chunk(in_rows)
+
+    def store(r, x):
+        dst_ref[pl.ds(r, chunk), :] = (x.astype(jnp.float32) * scale
+                                       ).astype(dst_ref.dtype)
+
+    def step(i, carry):
+        r = pl.multiple_of(i * chunk, chunk)
+        q = val_ref[pl.ds(r, chunk), :]
+        if fidelity == "int4":
+            b = q.astype(jnp.int32)
+            store(r, _unpack_nibble(b))
+            store(in_rows + r, _unpack_nibble(b >> 4))
+        else:
+            store(r, q)
+        return carry
+
+    jax.lax.fori_loop(0, in_rows // chunk, step, 0)
+
+
+def _as_rows(pool, padded: int):
+    """(n, elems) -> (n, padded / 128, 128), zero-padding each block."""
+    n, elems = pool.shape
+    if padded != elems:
+        pool = jnp.pad(pool, ((0, 0), (0, padded - elems)))
+    return pool.reshape(n, padded // _LANES, _LANES)
 
 
 def quantize_demote(src_pool, slot_ids, *, fidelity: str = "int8",
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Fused gather→quantize→pack for a demotion batch.
 
     ``src_pool``: (n_slots, block_elems) float pool; ``slot_ids``: (m,)
@@ -205,7 +276,9 @@ def quantize_demote(src_pool, slot_ids, *, fidelity: str = "int8",
     payload (m, packed_width) in the fidelity's storage dtype and the
     per-block f32 absmax scales (m, 1).  One pass: the source BlockSpec
     chases the slot list exactly like ``harvest_gather``, so the batch is
-    never staged densely at full precision.
+    never staged densely at full precision.  Blocks are zero-padded to
+    whole aligned lane rows (the pad quantizes to zero and is sliced off
+    on reload).
     """
     if fidelity not in FIDELITY_QMAX:
         raise ValueError(f"quantize_demote: unknown fidelity {fidelity!r} — "
@@ -213,38 +286,38 @@ def quantize_demote(src_pool, slot_ids, *, fidelity: str = "int8",
     n_slots, elems = src_pool.shape
     _check_slot_ids(slot_ids, n_slots, "quantize_demote")
     m = slot_ids.shape[0]
-    # int4 packs nibble pairs: pad an odd block width (the pad lane
-    # quantizes to zero and is sliced off on reload)
-    padded = elems + (elems % 2 if fidelity == "int4" else 0)
-    if padded != elems:
-        src_pool = jnp.pad(src_pool, ((0, 0), (0, padded - elems)))
-    width = _packed_width(padded, fidelity)
+    padded = _padded_elems(elems)
+    src = _as_rows(src_pool, padded)
+    rows = padded // _LANES
+    out_rows = _packed_width(elems, fidelity) // _LANES
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m,),
         in_specs=[
-            pl.BlockSpec((None, padded), lambda i, ids: (ids[i], 0)),
+            pl.BlockSpec((None, rows, _LANES), lambda i, ids: (ids[i], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, width), lambda i, ids: (i, 0)),
-            pl.BlockSpec((None, 1), lambda i, ids: (i, 0)),
+            pl.BlockSpec((None, out_rows, _LANES), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i, ids: (i, 0, 0)),
         ],
     )
     values, scales = pl.pallas_call(
         functools.partial(_quantize_kernel, fidelity=fidelity),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((m, width), FIDELITY_QDTYPE[fidelity]),
-            jax.ShapeDtypeStruct((m, 1), jnp.float32),
+            jax.ShapeDtypeStruct((m, out_rows, _LANES),
+                                 FIDELITY_QDTYPE[fidelity]),
+            jax.ShapeDtypeStruct((m, 1, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(slot_ids.astype(jnp.int32), src_pool)
-    return values, scales
+        interpret=interpret_mode(interpret),
+    )(slot_ids.astype(jnp.int32), src)
+    return values.reshape(m, out_rows * _LANES), scales.reshape(m, 1)
 
 
 def dequantize_reload(dst_pool, values, scales, slot_ids, *,
-                      fidelity: str = "int8", interpret: bool = True):
+                      fidelity: str = "int8",
+                      interpret: Optional[bool] = None):
     """Fused unpack→dequantize→scatter for a reload batch.
 
     Writes ``values[i] * scales[i]`` into ``dst_pool[slot_ids[i]]``; the
@@ -259,38 +332,41 @@ def dequantize_reload(dst_pool, values, scales, slot_ids, *,
     n_slots, elems = dst_pool.shape
     _check_slot_ids(slot_ids, n_slots, "dequantize_reload")
     m = slot_ids.shape[0]
-    padded = elems + (elems % 2 if fidelity == "int4" else 0)
-    width = _packed_width(padded, fidelity)
+    width = _packed_width(elems, fidelity)
     assert values.shape == (m, width), \
         f"dequantize_reload: values shape {values.shape} != ({m}, {width})"
-    if padded != elems:
-        dst_pool_in = jnp.pad(dst_pool, ((0, 0), (0, padded - elems)))
-    else:
-        dst_pool_in = dst_pool
+    padded = _padded_elems(elems)
+    dst = _as_rows(dst_pool, padded)
+    rows = padded // _LANES
+    in_rows = width // _LANES
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m,),
         in_specs=[
-            pl.BlockSpec((None, width), lambda i, ids: (i, 0)),
-            pl.BlockSpec((None, 1), lambda i, ids: (i, 0)),
-            pl.BlockSpec((None, padded), lambda i, ids: (ids[i], 0)),
+            pl.BlockSpec((None, in_rows, _LANES), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i, ids: (i, 0, 0)),
+            # the aliased pool stays in HBM: every reloaded row is
+            # overwritten, so its old contents are never read
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((None, padded), lambda i, ids: (ids[i], 0)),
+        out_specs=pl.BlockSpec((None, rows, _LANES),
+                               lambda i, ids: (ids[i], 0, 0)),
     )
     out = pl.pallas_call(
         functools.partial(_dequantize_kernel, fidelity=fidelity),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(dst_pool_in.shape, dst_pool.dtype),
-        # operand 3 = dst_pool (after the id list, values and scales):
+        out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
+        # operand 3 = dst (after the id list, values and scales):
         # aliasing it into the output preserves untouched slots
         input_output_aliases={3: 0},
-        interpret=interpret,
-    )(slot_ids.astype(jnp.int32), values, scales, dst_pool_in)
-    return out[:, :elems] if padded != elems else out
+        interpret=interpret_mode(interpret),
+    )(slot_ids.astype(jnp.int32), values.reshape(m, in_rows, _LANES),
+      scales.reshape(m, 1, 1), dst)
+    return out.reshape(n_slots, padded)[:, :elems]
 
 
-def harvest_scatter(dst_pool, staging, slot_ids, *, interpret: bool = True):
+def harvest_scatter(dst_pool, staging, slot_ids):
     """Write staging rows back into pool slots (reload completion).
 
     Implemented with a jnp scatter (aliasing-safe); the gather above is the

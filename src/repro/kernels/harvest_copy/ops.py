@@ -20,10 +20,6 @@ from repro.kernels.harvest_copy.kernel import (FIDELITY_QMAX, _check_slot_ids,
                                                quantize_demote)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _gather_jit(src_pool, slot_ids, *, chunk, interpret):
     return harvest_gather(src_pool, slot_ids, chunk=chunk, interpret=interpret)
@@ -31,9 +27,8 @@ def _gather_jit(src_pool, slot_ids, *, chunk, interpret):
 
 def gather_blocks(src_pool, slot_ids, *, chunk: int = 512,
                   interpret: Optional[bool] = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
     _check_slot_ids(slot_ids, src_pool.shape[0], "gather_blocks")
-    return _gather_jit(src_pool, slot_ids, chunk=chunk, interpret=interp)
+    return _gather_jit(src_pool, slot_ids, chunk=chunk, interpret=interpret)
 
 
 @jax.jit
@@ -56,11 +51,10 @@ def copy_blocks(src_pool, dst_pool, src_ids, dst_ids, *, chunk: int = 512,
                 interpret: Optional[bool] = None):
     """Fused gather→scatter: move ``src_pool[src_ids]`` straight into
     ``dst_pool[dst_ids]`` with no dense staging buffer."""
-    interp = (not _on_tpu()) if interpret is None else interpret
     _check_slot_ids(src_ids, src_pool.shape[0], "copy_blocks(src)")
     _check_slot_ids(dst_ids, dst_pool.shape[0], "copy_blocks(dst)")
     return _copy_jit(src_pool, dst_pool, src_ids, dst_ids, chunk=chunk,
-                     interpret=interp)
+                     interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -97,9 +91,8 @@ def quantize_blocks(src_pool, slot_ids, *, fidelity: str = "int8",
     _check_fidelity(fidelity, "quantize_blocks")
     _check_pool(src_pool, "quantize_blocks")
     _check_slot_ids(slot_ids, src_pool.shape[0], "quantize_blocks")
-    interp = (not _on_tpu()) if interpret is None else interpret
     return _quantize_jit(src_pool, slot_ids, fidelity=fidelity,
-                         interpret=interp)
+                         interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("fidelity", "interpret"))
@@ -120,8 +113,7 @@ def dequantize_blocks(dst_pool, values, scales, slot_ids, *,
     _check_slot_ids(slot_ids, dst_pool.shape[0], "dequantize_blocks")
     m = slot_ids.shape[0]
     elems = dst_pool.shape[1]
-    width = _packed_width(elems + (elems % 2 if fidelity == "int4" else 0),
-                          fidelity)
+    width = _packed_width(elems, fidelity)
     if tuple(values.shape) != (m, width):
         raise ValueError(
             f"dequantize_blocks: values shape {tuple(values.shape)} does not "
@@ -129,6 +121,5 @@ def dequantize_blocks(dst_pool, values, scales, slot_ids, *,
     if tuple(scales.shape) != (m, 1):
         raise ValueError(f"dequantize_blocks: scales shape "
                          f"{tuple(scales.shape)} != ({m}, 1)")
-    interp = (not _on_tpu()) if interpret is None else interpret
     return _dequantize_jit(dst_pool, values, scales, slot_ids,
-                           fidelity=fidelity, interpret=interp)
+                           fidelity=fidelity, interpret=interpret)

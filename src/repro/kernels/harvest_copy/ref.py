@@ -20,10 +20,11 @@ def harvest_copy_ref(src_pool, dst_pool, src_ids, dst_ids):
 def quantize_demote_ref(src_pool, slot_ids, fidelity: str = "int8"):
     """Dense oracle for the fused quantize kernel: gather, per-row absmax
     scale, quantize, pack."""
-    from repro.kernels.harvest_copy.kernel import FIDELITY_QMAX
+    from repro.kernels.harvest_copy.kernel import (FIDELITY_QMAX,
+                                                   _padded_elems)
     rows = jnp.take(src_pool, slot_ids, axis=0).astype(jnp.float32)
-    if fidelity == "int4" and rows.shape[1] % 2:
-        rows = jnp.pad(rows, ((0, 0), (0, 1)))
+    pad = _padded_elems(rows.shape[1]) - rows.shape[1]
+    rows = jnp.pad(rows, ((0, 0), (0, pad)))
     absmax = jnp.max(jnp.abs(rows), axis=1, keepdims=True)
     scales = jnp.where(absmax == 0.0, 1.0,
                        absmax / FIDELITY_QMAX[fidelity])
@@ -33,9 +34,10 @@ def quantize_demote_ref(src_pool, slot_ids, fidelity: str = "int8"):
     elif fidelity == "fp8":
         values = x.astype(jnp.float8_e4m3fn)
     elif fidelity == "int4":
+        # element j -> low nibble of byte j, element j + half -> high nibble
         q = jnp.clip(jnp.round(x), -7, 7).astype(jnp.int32)
-        q = q.reshape(q.shape[0], -1, 2)
-        values = ((q[..., 0] & 15) | ((q[..., 1] & 15) << 4)).astype(jnp.uint8)
+        lo, hi = jnp.split(q, 2, axis=1)
+        values = ((lo & 15) | ((hi & 15) << 4)).astype(jnp.uint8)
     else:
         raise ValueError(f"unknown fidelity {fidelity!r}")
     return values, scales.astype(jnp.float32)
@@ -49,8 +51,7 @@ def dequantize_reload_ref(dst_pool, values, scales, slot_ids,
         b = values.astype(jnp.int32)
         lo = (b & 15) - 2 * (b & 8)
         hi = ((b >> 4) & 15) - 2 * ((b >> 4) & 8)
-        x = jnp.stack([lo, hi], axis=-1).reshape(values.shape[0], -1)
-        x = x.astype(jnp.float32)
+        x = jnp.concatenate([lo, hi], axis=1).astype(jnp.float32)
     elif fidelity in ("int8", "fp8"):
         x = values.astype(jnp.float32)
     else:

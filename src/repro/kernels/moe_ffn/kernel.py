@@ -13,11 +13,14 @@ VMEM working set per step (targets):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _moe_ffn_kernel(x_ref, wi_ref, wg_ref, wo_ref, o_ref, acc_scr, *,
@@ -52,7 +55,7 @@ def _moe_ffn_kernel(x_ref, wi_ref, wg_ref, wo_ref, o_ref, acc_scr, *,
 
 def moe_ffn(xd, wi, wg, wo, *, activation: str = "silu",
             c_block: int = 128, f_block: int = 256,
-            interpret: bool = True):
+            interpret: Optional[bool] = None):
     """xd: (E, C, d);  wi/wg: (E, d, f);  wo: (E, f, d) -> (E, C, d)."""
     E, C, d = xd.shape
     f = wi.shape[2]
@@ -76,5 +79,5 @@ def moe_ffn(xd, wi, wg, wo, *, activation: str = "silu",
         out_specs=pl.BlockSpec((None, c_block, d), lambda e, c, fi: (e, c, 0)),
         out_shape=jax.ShapeDtypeStruct((E, C, d), xd.dtype),
         scratch_shapes=[pltpu.VMEM((c_block, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xd, wi, wg, wo)

@@ -9,15 +9,10 @@ import jax
 from repro.kernels.moe_ffn.kernel import moe_ffn
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=(
     "activation", "c_block", "f_block", "interpret"))
 def expert_ffn(xd, wi, wg, wo, *, activation: str = "silu",
                c_block: int = 128, f_block: int = 256,
                interpret: Optional[bool] = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
     return moe_ffn(xd, wi, wg, wo, activation=activation,
-                   c_block=c_block, f_block=f_block, interpret=interp)
+                   c_block=c_block, f_block=f_block, interpret=interpret)

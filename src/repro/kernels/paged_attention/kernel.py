@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -76,7 +78,7 @@ def paged_attention(q, pool_k, pool_v, block_table, q_pos, *,
                     scale: Optional[float] = None,
                     sliding_window: Optional[int] = None,
                     attention_chunk: Optional[int] = None,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (b, nq, hd); pool_k/v: (n_slots, bs, nkv, hd);
     block_table: (b, max_blk) int32; q_pos: (b,) int32 -> (b, nq, hd)."""
     b, nq, hd = q.shape
@@ -117,7 +119,7 @@ def paged_attention(q, pool_k, pool_v, block_table, q_pos, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, gq, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(table_c, block_table.astype(jnp.int32), q_pos.astype(jnp.int32),
       qr, pool_k, pool_v)
     return out.reshape(b, nq, hd)

@@ -9,18 +9,13 @@ import jax
 from repro.kernels.paged_attention.kernel import paged_attention
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=(
     "sliding_window", "attention_chunk", "interpret"))
 def decode_attention(q, pool_k, pool_v, block_table, q_pos, *,
                      sliding_window: Optional[int] = None,
                      attention_chunk: Optional[int] = None,
                      interpret: Optional[bool] = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
     return paged_attention(q, pool_k, pool_v, block_table, q_pos,
                            sliding_window=sliding_window,
                            attention_chunk=attention_chunk,
-                           interpret=interp)
+                           interpret=interpret)

@@ -46,7 +46,8 @@ import jax
 import numpy as np
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """The serving CLI's flags, validated."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
     ap.add_argument("--num-requests", type=int, default=8)
@@ -165,7 +166,7 @@ def main():
                     help="fraction of effective capacity the engaged "
                          "controller keeps free (default 0.15)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.monitor_interval_us and not args.with_churn:
         ap.error("--monitor-interval-us needs --with-churn (there is no "
                  "monitor to drive without a cluster trace)")
@@ -211,17 +212,18 @@ def main():
             and not 0.0 <= args.ctrl_headroom < 0.9:
         ap.error(f"--ctrl-headroom must be in [0, 0.9), got "
                  f"{args.ctrl_headroom}")
+    return args
 
-    from repro.configs import get_config
+
+def build_server(cfg, params, args: argparse.Namespace):
+    """The runtime and server the CLI serves ``cfg`` with, from its parsed
+    flags.  ``args.fidelity_policy`` may also be a ``FidelityPolicy``.
+    Returns ``(runtime, server)``."""
     from repro.core import (ClusterTrace, ClusterTraceConfig, CoalesceConfig,
                             HarvestRuntime, PrefetchConfig,
                             TopologyAwarePolicy, get_topology)
-    from repro.models import model as M
-    from repro.serving import (ControllerConfig, SpecDecodeConfig,
-                               TenantSpec, Workload)
+    from repro.serving import ControllerConfig, SpecDecodeConfig
 
-    cfg = get_config(args.arch).reduced()
-    params = M.init_params(jax.random.PRNGKey(args.seed), cfg)
     budget = int(args.peer_budget_mb * 2**20)
     topology = get_topology(args.topology) if args.topology else None
     budgets = (topology.device_budgets(budget) if topology
@@ -266,6 +268,20 @@ def main():
         chunk_prefill_tokens=args.chunk_prefill_tokens, spec_decode=spec,
         fidelity_policy=args.fidelity_policy, cold_tier=args.cold_tier,
         controller=controller)
+    return runtime, server
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    from repro.configs import get_config
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.models import model as M
+    from repro.serving import TenantSpec, Workload
+
+    use_compile_cache()
+    cfg = get_config(args.arch).reduced()
+    params = M.init_params(jax.random.PRNGKey(args.seed), cfg)
+    runtime, server = build_server(cfg, params, args)
     eng = server.engine
 
     if args.workload == "legacy":

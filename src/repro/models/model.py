@@ -26,7 +26,7 @@ from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models import xlstm as X
 from repro.models.moe import moe_layer
-from repro.models.sharding import ShardingRules, shard, shard_map
+from repro.models.sharding import ShardingRules, shard
 from repro.models.params import (  # noqa: F401  (re-exported)
     abstract_params, build_schema, init_params, param_count, param_specs)
 
@@ -392,7 +392,7 @@ def _decode_attention_carried(q, pools_full, layer, state, k_new, v_new,
     if peer_args:
         in_specs += [pool_spec, pool_spec, slot_spec, slot_spec]
     fn = functools.partial(local_fn, axis_names=axes)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=rules.mesh, in_specs=tuple(in_specs),
         out_specs=(rep, pool_spec, pool_spec), check_vma=False,
     )(q, pkf, pvf, layer, kvp.slot_req, kvp.slot_base, k_new, v_new,
@@ -439,7 +439,7 @@ def _decode_attention(q, layer_pools, q_pos, cfg, rules, peer_layer_pools=None):
     in_specs = [rep, pool_spec, pool_spec, pool_spec, pool_spec,
                 rep, rep, rep, rep] + [pool_spec] * len(peer_args)
     fn = functools.partial(local_fn, axis_names=axes)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=rules.mesh, in_specs=tuple(in_specs),
         out_specs=(rep, pool_spec, pool_spec), check_vma=False,
     )(q, pk, pv, sr, sb, k_new, v_new, a_slot, a_off, *peer_args)

@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import _activation, mlp
-from repro.models.sharding import shard, shard_map
+from repro.models.sharding import shard
 
 
 def router_topk(logits, top_k: int):
@@ -232,7 +232,7 @@ def _moe_layer_local(x, p, cfg: ModelConfig, rules,
 
     daxes = (dax,) if isinstance(dax, str) else tuple(dax)
     eaxes = (eax,) if isinstance(eax, str) else tuple(eax)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local, mesh=rules.mesh,
         in_specs=(P(daxes), P(daxes), P(eaxes, daxes), P(eaxes, daxes),
                   P(eaxes, None, daxes)),

@@ -8,6 +8,7 @@ shardings cannot drift apart.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
@@ -239,8 +240,22 @@ def _walk(schema, fn, path=()):
     return {k: _walk(v, fn, path + (k,)) for k, v in schema.items()}
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "scale"))
+def _normal_leaf(key, shape, dtype, scale):
+    """One leaf's scaled normal draw as one program: the f32 draw, the
+    scale and the cast fuse, so no full-size f32 buffer is ever live
+    beside the weights already drawn."""
+    x = jax.random.normal(key, shape, jnp.float32)
+    # a no-op rounding that keeps XLA from folding the normal transform's
+    # sqrt(2) into ``scale``: the folded product rounds differently, and
+    # the values must stay those of the unfused draw
+    x = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=23)
+    return (x * scale).astype(dtype)
+
+
 def init_params(rng, cfg: ModelConfig):
-    """Materialise parameters (smoke/reduced configs only)."""
+    """Materialise parameters on the default device, one leaf per
+    program, at any width the device's memory holds."""
     schema = build_schema(cfg)
     counter = [0]
 
@@ -256,7 +271,7 @@ def init_params(rng, cfg: ModelConfig):
             return jnp.full(pd.shape, float(pd.init.split(":")[1]), dtype)
         fan_in = pd.shape[-2] if len(pd.shape) >= 2 else pd.shape[-1]
         scale = min(0.02, 1.0 / math.sqrt(max(fan_in, 1)))
-        return (jax.random.normal(key, pd.shape, jnp.float32) * scale).astype(dtype)
+        return _normal_leaf(key, pd.shape, dtype, scale)
 
     return _walk(schema, make)
 

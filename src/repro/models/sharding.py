@@ -13,19 +13,6 @@ from typing import Optional, Tuple, Union
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# jax.shard_map was promoted out of jax.experimental in newer releases
-# (renaming check_rep -> check_vma along the way); resolve whichever this
-# jax ships so model code has one spelling, the new one.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                    # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, /, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _experimental_shard_map(f, **kwargs)
-
 Axis = Union[None, str, Tuple[str, ...]]
 
 

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import layer_norm, rms_norm
-from repro.models.sharding import shard, shard_map
+from repro.models.sharding import shard
 
 LOG_EPS = -1e30
 
@@ -264,7 +264,7 @@ def slstm_block(x, p, cfg: ModelConfig, rules=None,
             from jax.sharding import PartitionSpec as P
             daxes = (dax,) if isinstance(dax, str) else tuple(dax)
             bspec = P(daxes)
-            hs, c, n, m, h = shard_map(
+            hs, c, n, m, h = jax.shard_map(
                 scan_cells, mesh=rules.mesh,
                 in_specs=(bspec, bspec, bspec, bspec, bspec, P()),
                 out_specs=(bspec,) * 5, check_vma=False,

@@ -78,6 +78,7 @@ SLO attainment) aggregated by ``EngineStats.summary()``.
 """
 from __future__ import annotations
 
+import functools
 import heapq
 import math
 from dataclasses import dataclass, field
@@ -102,6 +103,12 @@ from repro.serving.admission import (ADMISSION, AdmissionPolicy,
                                      AdmissionView, StabilityAdmission)
 from repro.serving.control import ControllerConfig, StabilityController
 from repro.serving.scheduler import SCHEDULERS, SLO_CLASSES, Request
+
+#: the model's jitted entry points, shared by every engine in the process
+#: (the frozen config is a static argument), so a second engine over the
+#: same model compiles nothing
+_serve_step = jax.jit(M.serve_step, static_argnames=("cfg", "rules"))
+_forward = jax.jit(M.forward, static_argnames=("cfg", "rules", "want_kv"))
 
 
 @dataclass
@@ -625,10 +632,9 @@ class HarvestServingEngine:
         self._arrivals: List[Tuple[float, int, Request]] = []
         self.stats = EngineStats()
         self._next_id = 0
-        self._decode_fn = jax.jit(
-            lambda p, st: M.serve_step(p, st, cfg, None))
-        self._prefill_fn = jax.jit(
-            lambda p, batch: M.forward(p, batch, cfg, None, want_kv=True))
+        self._decode_fn = functools.partial(_serve_step, cfg=cfg, rules=None)
+        self._prefill_fn = functools.partial(_forward, cfg=cfg, rules=None,
+                                             want_kv=True)
 
         # per-token decode compute estimate (weight-read bound)
         pc = cfg.param_counts()

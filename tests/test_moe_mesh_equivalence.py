@@ -26,7 +26,8 @@ PROG = textwrap.dedent("""
                                     capacity_factor=8.0))
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     lp = jax.tree.map(lambda t: t[0], params["layers"])
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = ShardingRules(mesh, dict(DEFAULT_RULES))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32), jnp.float32)
 
