@@ -1,0 +1,7 @@
+"""Roofline share of the decode program: least time from its operations and
+bytes over its device time, in percent (batch cell)."""
+from bench.harness import readers
+
+
+def read(run):
+    return readers.decode_roofline(run)

@@ -1,0 +1,7 @@
+"""Device milliseconds per run of the decode program, from the trace (chat
+cells)."""
+from bench.harness import readers
+
+
+def read(run):
+    return readers.decode_step_ms(run)

@@ -1,0 +1,8 @@
+"""Harness self-checks: ``python -m pytest bench/tests`` from the root."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
