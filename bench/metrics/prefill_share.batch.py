@@ -1,0 +1,7 @@
+"""Percent of the traced span's ``engine.step`` time covered by
+``engine.prefill`` spans (batch cells)."""
+from bench.harness import spans
+
+
+def read(run):
+    return spans.read(run, spans.prefill_share)

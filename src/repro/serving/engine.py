@@ -81,6 +81,7 @@ from __future__ import annotations
 import functools
 import heapq
 import math
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -89,6 +90,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import tracing
 from repro.core.allocator import HarvestAllocator
 from repro.core.monitor import PeerMonitor
 from repro.core.policy import FIDELITY_POLICIES, FidelityPolicy
@@ -237,6 +239,15 @@ class SpecDecodeConfig:
         return e
 
 
+def _block_args(bid, slot) -> dict:
+    """Span args of a KV block: its owner (none for a shared prefix-cache
+    block), its index or digest, and its pool slot."""
+    args = {"block": bid[1], "slot": slot}
+    if bid[0] != "px":
+        args["req"] = bid[0]
+    return args
+
+
 def _pct(xs: List[float], q: float) -> float:
     """Nearest-rank percentile; 0.0 on an empty sample (guarded)."""
     if not xs:
@@ -247,6 +258,8 @@ def _pct(xs: List[float], q: float) -> float:
 
 @dataclass
 class EngineStats:
+    """Counts and seconds of a run.  Every ``*_s`` field is on the
+    engine's simulated clock, never a wall or device time."""
     clock_s: float = 0.0      # simulated wall time
     compute_s: float = 0.0    # decode compute windows
     prefill_s: float = 0.0    # prefill compute windows
@@ -771,25 +784,29 @@ class HarvestServingEngine:
 
     # ----------------------------------------------------------- payload
     def _on_evict(self, bid, slot):
-        if self.prefetcher is not None:
-            self.prefetcher.on_evict(bid)
-        if self.pool_k is None:
-            return
-        data = np.stack([np.asarray(self.pool_k[:, slot]),
-                         np.asarray(self.pool_v[:, slot])], axis=1)
-        ent = self.kv_mgr.table.get(bid)
-        if ent is not None and ent.fidelity.is_quantized:
-            data = self._degrade(data, ent.fidelity)
-        self.kv_mgr.write_payload(*bid, data)
-        self.slot_req[slot] = -1
+        with tracing.span("kv.evict", **_block_args(bid, slot)):
+            if self.prefetcher is not None:
+                self.prefetcher.on_evict(bid)
+            if self.pool_k is None:
+                return
+            with tracing.span("device.wait"):
+                data = np.stack([np.asarray(self.pool_k[:, slot]),
+                                 np.asarray(self.pool_v[:, slot])], axis=1)
+            ent = self.kv_mgr.table.get(bid)
+            if ent is not None and ent.fidelity.is_quantized:
+                data = self._degrade(data, ent.fidelity)
+            self.kv_mgr.write_payload(*bid, data)
+            self.slot_req[slot] = -1
 
     def _on_reload(self, bid, slot):
-        data = self.kv_mgr.read_payload(*bid)
-        assert data is not None, f"reload of lost block {bid}"
-        self.pool_k = self.pool_k.at[:, slot].set(data[:, 0])
-        self.pool_v = self.pool_v.at[:, slot].set(data[:, 1])
-        self.slot_req[slot] = self.row_of.get(bid[0], -1)
-        self.slot_base[slot] = self.kv_mgr.table[bid].base_pos
+        with tracing.span("kv.reload", **_block_args(bid, slot)):
+            data = self.kv_mgr.read_payload(*bid)
+            assert data is not None, f"reload of lost block {bid}"
+            with tracing.span("kv.write"):
+                self.pool_k = self.pool_k.at[:, slot].set(data[:, 0])
+                self.pool_v = self.pool_v.at[:, slot].set(data[:, 1])
+            self.slot_req[slot] = self.row_of.get(bid[0], -1)
+            self.slot_base[slot] = self.kv_mgr.table[bid].base_pos
 
     # ------------------------------------------------------------ states
     def _init_states(self):
@@ -874,8 +891,10 @@ class HarvestServingEngine:
                     arrival_t=arrival_t, slo=slo, priority=priority,
                     tenant=tenant, ttft_slo_s=ttft_slo_s,
                     e2e_slo_s=e2e_slo_s, on_token=on_token,
-                    enqueue_t=arrival_t, enqueue_step=self.stats.steps)
+                    enqueue_t=arrival_t, enqueue_step=self.stats.steps,
+                    submitted_wall=time.perf_counter())
         self._next_id += 1
+        tracing.event("engine.submit", req=r.req_id)
         self._req_slo[r.req_id] = slo
         if arrival_t <= now:
             self.waiting.append(r)
@@ -937,8 +956,11 @@ class HarvestServingEngine:
                 t += self._charge_critical(reload_ops)
                 self._charge_writeback(alloc_ops)
                 src = self.kv_mgr.store.table[ckey].local_slot
-                self.pool_k = self.pool_k.at[:, slot].set(self.pool_k[:, src])
-                self.pool_v = self.pool_v.at[:, slot].set(self.pool_v[:, src])
+                with tracing.span("kv.write"):
+                    self.pool_k = self.pool_k.at[:, slot].set(
+                        self.pool_k[:, src])
+                    self.pool_v = self.pool_v.at[:, slot].set(
+                        self.pool_v[:, src])
             else:
                 t += self._charge_critical(
                     self.kv_mgr.adopt_block(r.req_id, j, ckey))
@@ -974,6 +996,13 @@ class HarvestServingEngine:
         return logits, out, npre, n_pad
 
     def _prefill(self, r: Request) -> None:
+        n = len(r.prompt) + len(r.output)
+        with tracing.span("engine.prefill", req=r.req_id, tokens=n,
+                          padded=self.bs * math.ceil(n / self.bs)) as sp:
+            self._prefill_inline(r)
+            sp.set(cached=r.cached_prefix_blocks * self.bs)
+
+    def _prefill_inline(self, r: Request) -> None:
         prefix = r.prompt + r.output            # rollback re-prefills output
         n = len(prefix)
         logits, out, npre, n_pad = self._prefill_forward(prefix)
@@ -1005,22 +1034,30 @@ class HarvestServingEngine:
             if npre:   # prefix embeddings occupy the first npre positions
                 k, v = k[:, :, npre:], v[:, :, npre:]
             nb = math.ceil(n / self.bs)
-            for j in range(len(matched), nb):
-                slot, ops = self.kv_mgr.allocate_block(r.req_id, j, j * self.bs)
-                self._charge_writeback(ops)
-                lo, hi = j * self.bs, min((j + 1) * self.bs, n_pad)
-                self.pool_k = self.pool_k.at[:, slot, :hi - lo].set(
-                    k[:, 0, lo:hi].astype(jnp.float32))
-                self.pool_v = self.pool_v.at[:, slot, :hi - lo].set(
-                    v[:, 0, lo:hi].astype(jnp.float32))
-                self.slot_req[slot] = row
-                self.slot_base[slot] = j * self.bs
-                ent = self.kv_mgr.table[(r.req_id, j)]
-                ent.filled = min(self.bs, n - lo) if lo < n else 0
+            with tracing.span("engine.prefill.write",
+                              blocks=nb - len(matched)):
+                for j in range(len(matched), nb):
+                    slot, ops = self.kv_mgr.allocate_block(r.req_id, j,
+                                                           j * self.bs)
+                    self._charge_writeback(ops)
+                    lo, hi = j * self.bs, min((j + 1) * self.bs, n_pad)
+                    # the slices and the writes are eager dispatches,
+                    # each of which can hold the host behind the device
+                    with tracing.span("kv.write"):
+                        self.pool_k = self.pool_k.at[:, slot, :hi - lo].set(
+                            k[:, 0, lo:hi].astype(jnp.float32))
+                        self.pool_v = self.pool_v.at[:, slot, :hi - lo].set(
+                            v[:, 0, lo:hi].astype(jnp.float32))
+                    self.slot_req[slot] = row
+                    self.slot_base[slot] = j * self.bs
+                    ent = self.kv_mgr.table[(r.req_id, j)]
+                    ent.filled = min(self.bs, n - lo) if lo < n else 0
         if out.states is not None:
             self._set_state_row(row, out.states)
 
-        nxt = self._sample(np.asarray(logits[0, npre + n - 1]))
+        with tracing.span("device.wait"):
+            last = np.asarray(logits[0, npre + n - 1])
+        nxt = self._sample(last)
         if not r.output:
             r.output.append(int(nxt))
             self.stats.tokens_out += 1
@@ -1111,10 +1148,11 @@ class HarvestServingEngine:
                 ent = self.kv_mgr.table[(r.req_id, j)]
                 slot = ent.local_slot
                 lo, hi = j * self.bs, min((j + 1) * self.bs, n_pad)
-                self.pool_k = self.pool_k.at[:, slot, :hi - lo].set(
-                    k[:, 0, lo:hi].astype(jnp.float32))
-                self.pool_v = self.pool_v.at[:, slot, :hi - lo].set(
-                    v[:, 0, lo:hi].astype(jnp.float32))
+                with tracing.span("kv.write"):
+                    self.pool_k = self.pool_k.at[:, slot, :hi - lo].set(
+                        k[:, 0, lo:hi].astype(jnp.float32))
+                    self.pool_v = self.pool_v.at[:, slot, :hi - lo].set(
+                        v[:, 0, lo:hi].astype(jnp.float32))
                 self.slot_req[slot] = row
                 self.slot_base[slot] = j * self.bs
                 ent.filled = min(self.bs, n - lo) if lo < n else 0
@@ -1283,10 +1321,11 @@ class HarvestServingEngine:
                                                        j * self.bs)
                 self._charge_writeback(ops)
                 lo, hi = j * self.bs, min((j + 1) * self.bs, n_pad)
-                self.pool_k = self.pool_k.at[:, slot, :hi - lo].set(
-                    jnp.asarray(job.k[:, lo:hi]))
-                self.pool_v = self.pool_v.at[:, slot, :hi - lo].set(
-                    jnp.asarray(job.v[:, lo:hi]))
+                with tracing.span("kv.write"):
+                    self.pool_k = self.pool_k.at[:, slot, :hi - lo].set(
+                        jnp.asarray(job.k[:, lo:hi]))
+                    self.pool_v = self.pool_v.at[:, slot, :hi - lo].set(
+                        jnp.asarray(job.v[:, lo:hi]))
                 self.slot_req[slot] = row
                 self.slot_base[slot] = j * self.bs
                 ent = self.kv_mgr.table[(r.req_id, j)]
@@ -1487,80 +1526,90 @@ class HarvestServingEngine:
         request), then the scheduler assigns batch rows.  Admitted
         requests are prefilled (new / rolled back) or resumed (reload
         their evicted prefix)."""
-        now = self._now()
-        view = AdmissionView(
-            now=now, free_rows=len(self.free_rows), num_slots=self.n_slots,
-            pinned_blocks=sum(self._blocks_needed(r) for r in self.running),
-            num_running=len(self.running),
-            blocks_needed=self._blocks_needed,
-            est_prefill_s=self._est_prefill_s,
-            pending_prefill_s=sum(self._remaining_prefill_s(r)
-                                  for r in self.running if r.needs_prefill))
-        eligible, shed = self.admission.select(list(self.waiting), view)
-        for r in shed:
-            self.waiting.remove(r)
-            self._shed(r, now)
-        deferred = [w for w in self.waiting if w not in eligible]
-        pinned_blocks = view.pinned_blocks
-        # regime-dependent batch cap (stability controller, engaged only):
-        # the `cap < self.B` guard means an uncapped controller leaves the
-        # scheduler's choice set — and with it every admission decision —
-        # bit-exact with the controller-free engine
-        cap = self.B if self._controller is None \
-            else self._controller.batch_cap
-        admissible = []
-        for cand in eligible:
-            if cap < self.B and len(self.running) + len(admissible) >= cap:
-                break
-            need = self._blocks_needed(cand)
-            if pinned_blocks + need > self.n_slots or not self.free_rows:
-                break
-            pinned_blocks += need
-            admissible.append(cand)
-        rest = [w for w in eligible if w not in admissible] + deferred
-        self.waiting = admissible
-        admitted = self.scheduler.admit(self.waiting, self.free_rows)
-        self.waiting = self.waiting + rest
-        for r in admitted:
-            if r.admit_t is None:          # queue wait ends at FIRST admit
-                r.admit_t = now
-            self.running.append(r)
-            self.row_of[r.req_id] = r.row
-            self.kv_mgr.pinned.add(r.req_id)
-            if r.needs_prefill:
-                # chunked mode: the prefill advances in resumable chunks
-                # from the next _prefill_chunks pass instead of inline
-                if self._chunk_tokens is None:
-                    self._prefill(r)
-            elif r.req_id in self._pf_jobs:
-                self._adopt_streamed(r)
-            else:
-                self._resume(r)
+        with tracing.span("engine.admit") as sp:
+            now = self._now()
+            view = AdmissionView(
+                now=now, free_rows=len(self.free_rows),
+                num_slots=self.n_slots,
+                pinned_blocks=sum(self._blocks_needed(r)
+                                  for r in self.running),
+                num_running=len(self.running),
+                blocks_needed=self._blocks_needed,
+                est_prefill_s=self._est_prefill_s,
+                pending_prefill_s=sum(self._remaining_prefill_s(r)
+                                      for r in self.running
+                                      if r.needs_prefill))
+            eligible, shed = self.admission.select(list(self.waiting), view)
+            for r in shed:
+                self.waiting.remove(r)
+                self._shed(r, now)
+            deferred = [w for w in self.waiting if w not in eligible]
+            pinned_blocks = view.pinned_blocks
+            # regime-dependent batch cap (stability controller, engaged
+            # only): the `cap < self.B` guard means an uncapped controller
+            # leaves the scheduler's choice set — and with it every
+            # admission decision — bit-exact with the controller-free engine
+            cap = self.B if self._controller is None \
+                else self._controller.batch_cap
+            admissible = []
+            for cand in eligible:
+                if (cap < self.B
+                        and len(self.running) + len(admissible) >= cap):
+                    break
+                need = self._blocks_needed(cand)
+                if pinned_blocks + need > self.n_slots or not self.free_rows:
+                    break
+                pinned_blocks += need
+                admissible.append(cand)
+            rest = [w for w in eligible if w not in admissible] + deferred
+            self.waiting = admissible
+            admitted = self.scheduler.admit(self.waiting, self.free_rows)
+            self.waiting = self.waiting + rest
+            if tracing.on():
+                sp.set(admitted=[r.req_id for r in admitted])
+            wall = time.perf_counter()
+            for r in admitted:
+                if r.admit_t is None:      # queue wait ends at FIRST admit
+                    r.admit_t = now
+                    r.admitted_wall = wall
+                self.running.append(r)
+                self.row_of[r.req_id] = r.row
+                self.kv_mgr.pinned.add(r.req_id)
+                if r.needs_prefill:
+                    # chunked mode: the prefill advances in resumable
+                    # chunks from the next _prefill_chunks pass, not inline
+                    if self._chunk_tokens is None:
+                        self._prefill(r)
+                elif r.req_id in self._pf_jobs:
+                    self._adopt_streamed(r)
+                else:
+                    self._resume(r)
 
     def _resume(self, r: Request) -> None:
         """Resuming a preempted request: reload its blocks.  The reloads
         are critical for THIS step (the request decodes immediately); a
         lossy revocation while preempted forces a prefix rebuild."""
         nb = math.ceil((r.pos + 1) / self.bs)
-        plan = self.kv_mgr.plan_reloads(
-            [(r.req_id, j) for j in range(nb)])
-        for bid in plan.touched:
-            self._claim_prefetch(bid)
-        t = self._charge_critical(plan.ops)
-        if self.mode == "async":
-            self._step_waits.extend(plan.attached)
-        if plan.lost is not None:
-            # lossy revocation while preempted: rebuild the prefix
-            self.stats.recomputes += 1
-            if self.prefetcher is not None:
-                self.prefetcher.cancel_owner(r.req_id)
-            self.kv_mgr.free_request(r.req_id)
-            self._restart_prefill(r)
-        else:
-            self.row_tokens[r.row] = r.output[-1]
-            self.row_pos[r.row] = r.pos
-        if self.mode == "sync":
-            self.stats.clock_s += t
+        with tracing.span("engine.resume", req=r.req_id, blocks=nb):
+            plan = self.kv_mgr.plan_reloads(
+                [(r.req_id, j) for j in range(nb)])
+            for bid in plan.touched:
+                self._claim_prefetch(bid)
+            t = self._charge_critical(plan.ops)
+            if self.mode == "async":
+                self._step_waits.extend(plan.attached)
+            if plan.lost is not None:
+                # lossy revocation while preempted: rebuild the prefix
+                self.stats.recomputes += 1
+                if self.prefetcher is not None:
+                    self.prefetcher.cancel_owner(r.req_id)
+                self.kv_mgr.free_request(r.req_id)
+                self._restart_prefill(r)
+            else:
+                self.row_tokens[r.row] = r.output[-1]
+                self.row_pos[r.row] = r.pos
+            if self.mode == "sync":
+                self.stats.clock_s += t
 
     def _restart_prefill(self, r: Request) -> None:
         """Lossy-revocation rollback: the whole prefix must be rebuilt.
@@ -1742,44 +1791,48 @@ class HarvestServingEngine:
         """Sample one token per decoding request, commit it, and stream it
         to the request's callback (the clock has already advanced past
         this step's window, so the timestamp is the token's ready time)."""
-        logits_np = np.asarray(logits)
-        now = self._now()
-        for r in reqs:
-            tok = self._sample(logits_np[r.row])
-            r.output.append(tok)
-            r.decode_steps += 1
-            self.stats.tokens_out += 1
-            self.row_tokens[r.row] = tok
-            self.row_pos[r.row] = r.pos
-            if r.first_token_t is None:
-                r.first_token_t = now
-            if r.on_token is not None:
-                r.on_token(tok, r)
+        with tracing.span("engine.sample", rows=len(reqs)):
+            with tracing.span("device.wait"):
+                logits_np = np.asarray(logits)
+            now = self._now()
+            for r in reqs:
+                tok = self._sample(logits_np[r.row])
+                r.output.append(tok)
+                r.decode_steps += 1
+                self.stats.tokens_out += 1
+                self.row_tokens[r.row] = tok
+                self.row_pos[r.row] = r.pos
+                if r.first_token_t is None:
+                    r.first_token_t = now
+                if r.on_token is not None:
+                    r.on_token(tok, r)
 
     def _retire(self) -> None:
         """Release finished requests: batch row, KV blocks, prefetches."""
         now = self._now()
-        for r in list(self.running):
-            if not r.done:
-                continue
-            r.state = "done"
-            r.finish_t = now
-            self._record(r)
-            self.running.remove(r)
-            self.finished.append(r)
-            self.free_rows.append(r.row)
-            for slot in np.nonzero(self.slot_req == r.row)[0]:
-                self.slot_req[slot] = -1
-            if self._pcache is not None and self.L_kv:
-                # publish-on-retire: the prompt's full blocks transfer to
-                # the trie (zero copy) instead of being freed below
-                self._pcache.publish(r.req_id, r.prompt)
-            self.kv_mgr.free_request(r.req_id)
-            if self.prefetcher is not None:
-                self.prefetcher.cancel_owner(r.req_id)
-            self.row_of.pop(r.req_id, None)
-            self._req_slo.pop(r.req_id, None)
-            r.row = None
+        with tracing.span("engine.retire") as sp:
+            retired = [r for r in self.running if r.done]
+            if tracing.on():
+                sp.set(retired=[r.req_id for r in retired])
+            for r in retired:
+                r.state = "done"
+                r.finish_t = now
+                self._record(r)
+                self.running.remove(r)
+                self.finished.append(r)
+                self.free_rows.append(r.row)
+                for slot in np.nonzero(self.slot_req == r.row)[0]:
+                    self.slot_req[slot] = -1
+                if self._pcache is not None and self.L_kv:
+                    # publish-on-retire: the prompt's full blocks transfer
+                    # to the trie (zero copy) instead of being freed below
+                    self._pcache.publish(r.req_id, r.prompt)
+                self.kv_mgr.free_request(r.req_id)
+                if self.prefetcher is not None:
+                    self.prefetcher.cancel_owner(r.req_id)
+                self.row_of.pop(r.req_id, None)
+                self._req_slo.pop(r.req_id, None)
+                r.row = None
 
     # -------------------------------------------------------------- step
     def step(self) -> bool:
@@ -1787,6 +1840,12 @@ class HarvestServingEngine:
         when all work is done.  Clock-driven arrivals become visible
         first; a request-free gap fast-forwards the clock to the next
         arrival (charged as ``idle_s``) instead of spinning steps."""
+        with tracing.span("engine.step", step=self.stats.steps,
+                          running=len(self.running),
+                          waiting=len(self.waiting)):
+            return self._step()
+
+    def _step(self) -> bool:
         self._admit_arrivals()
         if self._disagg:
             self._collect_streams()
@@ -1832,38 +1891,47 @@ class HarvestServingEngine:
         # that finished THIS step (their first token IS this window's work)
         decoding = [r for r in self.running
                     if not r.needs_prefill and r not in self._chunk_done]
-        plan = self._plan_fetches(decoding)
-        reload_t = self._launch_transfers(plan, decoding)
-        # coalesce + submit the step's whole critical set: one batched
-        # lane occupancy per link direction (no-op without a planner)
-        reload_t += self._flush_step_plan()
-        # timeline-driven pressure: external budget changes land HERE, while
-        # this step's transfers are already in flight on the lanes, instead
-        # of in the gap between steps (a revoked peer block that this step's
-        # reads depend on has already been made local above, so the step
-        # itself is safe — the revocation hits the resident-in-peer tail)
-        self._poll_pressure()
-        n_dec = len(decoding)
-        w_dec = self._estimate_compute(n_dec) if n_dec else 0.0
-        compute_t = self._step_window(n_dec, chunk_tokens, w_dec)
-        if self.prefetcher is not None:
-            # worst-case slots the next allocations may claim: one append
-            # block per running request + the head-of-line waiter's whole
-            # working set (prefill allocations OR resume reloads of blocks
-            # the prefetcher did not cover) — so a prefetch can never be
-            # the reason a later allocation evicts
-            floor = len(self.running) + (
-                self._blocks_needed(self.waiting[0]) if self.waiting else 0)
-            for op in self.prefetcher.run(compute_t, running=self.running,
-                                          waiting=self.waiting,
-                                          slot_floor=floor):
-                # speculative seconds: accounted as hidden at issue; any
-                # residual wait surfaces as stall in a later step.  lane_s
-                # is the occupancy actually charged (== seconds solo, less
-                # the saved setup inside a coalesced batch)
-                self.stats.reload_s += op.lane_s
-                self.stats.hidden_s += op.lane_s
-        logits = self._compute() if n_dec else None
+        with tracing.span("engine.plan"):
+            plan = self._plan_fetches(decoding)
+            reload_t = self._launch_transfers(plan, decoding)
+            # coalesce + submit the step's whole critical set: one batched
+            # lane occupancy per link direction (no-op without a planner)
+            reload_t += self._flush_step_plan()
+            # timeline-driven pressure: external budget changes land HERE,
+            # while this step's transfers are already in flight on the
+            # lanes, instead of in the gap between steps (a revoked peer
+            # block that this step's reads depend on has already been made
+            # local above, so the step itself is safe — the revocation hits
+            # the resident-in-peer tail)
+            self._poll_pressure()
+            n_dec = len(decoding)
+            w_dec = self._estimate_compute(n_dec) if n_dec else 0.0
+            compute_t = self._step_window(n_dec, chunk_tokens, w_dec)
+            if self.prefetcher is not None:
+                # worst-case slots the next allocations may claim: one
+                # append block per running request + the head-of-line
+                # waiter's whole working set (prefill allocations OR resume
+                # reloads of blocks the prefetcher did not cover) — so a
+                # prefetch can never be the reason a later allocation evicts
+                floor = len(self.running) + (
+                    self._blocks_needed(self.waiting[0])
+                    if self.waiting else 0)
+                for op in self.prefetcher.run(compute_t,
+                                              running=self.running,
+                                              waiting=self.waiting,
+                                              slot_floor=floor):
+                    # speculative seconds: accounted as hidden at issue; any
+                    # residual wait surfaces as stall in a later step.
+                    # lane_s is the occupancy actually charged (== seconds
+                    # solo, less the saved setup inside a coalesced batch)
+                    self.stats.reload_s += op.lane_s
+                    self.stats.hidden_s += op.lane_s
+        logits = None
+        if n_dec:
+            with tracing.span("engine.decode", rows=n_dec) as sp:
+                if tracing.on():
+                    sp.set(attended=sum(r.pos + 1 for r in decoding))
+                logits = self._compute()
         self._account_step(compute_t, reload_t,
                            prefill_share=compute_t - w_dec)
         if logits is not None:

@@ -39,6 +39,9 @@ class Request:
     record the latency/SLO stats are computed from::
 
         arrival_t -> [queue] -> admit_t -> first_token_t -> finish_t
+
+    ``submitted_wall`` and ``admitted_wall`` are the only wall-clock
+    stamps: when the engine took the request and first admitted it.
     """
     req_id: int
     prompt: List[int]
@@ -69,6 +72,9 @@ class Request:
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
     preempt_count: int = 0
+    # ---- wall-clock stamps (``time.perf_counter()`` seconds) -------------
+    submitted_wall: Optional[float] = None   # handed to the engine
+    admitted_wall: Optional[float] = None    # FIRST admission
 
     @property
     def pos(self) -> int:

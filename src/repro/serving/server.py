@@ -83,31 +83,45 @@ class RequestHandle:
     # lifecycle timestamps (simulated clock; None until reached)
     @property
     def arrival_t(self) -> float:
+        """Arrival on the engine's simulated clock."""
         return self._req.arrival_t
 
     @property
     def admit_t(self) -> Optional[float]:
+        """First admission on the engine's simulated clock."""
         return self._req.admit_t
 
     @property
     def first_token_t(self) -> Optional[float]:
+        """First token on the engine's simulated clock."""
         return self._req.first_token_t
 
     @property
     def finish_t(self) -> Optional[float]:
+        """Retirement on the engine's simulated clock."""
         return self._req.finish_t
 
     @property
     def ttft_s(self) -> Optional[float]:
+        """Simulated seconds from arrival to the first token."""
         if self._req.first_token_t is None:
             return None
         return self._req.first_token_t - self._req.arrival_t
 
     @property
     def e2e_s(self) -> Optional[float]:
+        """Simulated seconds from arrival to retirement."""
         if self._req.finish_t is None:
             return None
         return self._req.finish_t - self._req.arrival_t
+
+    @property
+    def queued_s(self) -> Optional[float]:
+        """Wall-clock seconds from submission to the first admission
+        (``time.perf_counter``); None until admitted."""
+        if self._req.admitted_wall is None:
+            return None
+        return self._req.admitted_wall - self._req.submitted_wall
 
     def __repr__(self):
         return (f"RequestHandle(req_id={self.req_id}, state={self.state!r}, "
