@@ -108,9 +108,37 @@ from repro.serving.scheduler import SCHEDULERS, SLO_CLASSES, Request
 
 #: the model's jitted entry points, shared by every engine in the process
 #: (the frozen config is a static argument), so a second engine over the
-#: same model compiles nothing
-_serve_step = jax.jit(M.serve_step, static_argnames=("cfg", "rules"))
+#: same model compiles nothing.  The decode step takes its state donated:
+#: the KV pools its scan carries are updated in place, not copied.
+_serve_step = jax.jit(M.serve_step, static_argnames=("cfg", "rules"),
+                      donate_argnames=("state",))
 _forward = jax.jit(M.forward, static_argnames=("cfg", "rules", "want_kv"))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_blocks(pool_k, pool_v, slots, k, v):
+    """Write ``len(slots)`` blocks into the K and V pools in place.
+
+    ``k``/``v``: ``(L, 1, S, nkv, hd)`` in any float dtype, the blocks in
+    their last ``len(slots) * block_size`` positions (a prefill's output
+    keeps its prefix embeddings in front).  A slot of ``n_slots`` or more
+    skips its block.  The pools are donated: the caller must hold no
+    other reference to them.  One block-sized update per block keeps the
+    pools in their own layout (a scatter of many blocks has the TPU
+    compiler relayout, i.e. copy, the whole pool).
+    """
+    n_slots, bs = pool_k.shape[1:3]
+    nb = slots.shape[0]
+    off = k.shape[2] - nb * bs
+
+    def body(i, pools):
+        def put(pools):
+            return tuple(jax.lax.dynamic_update_slice_in_dim(
+                pool, jax.lax.dynamic_slice_in_dim(
+                    x, off + i * bs, bs, axis=2).astype(pool.dtype),
+                slots[i], axis=1) for pool, x in zip(pools, (k, v)))
+        return jax.lax.cond(slots[i] < n_slots, put, lambda p: p, pools)
+    return jax.lax.fori_loop(0, nb, body, (pool_k, pool_v))
 
 
 @dataclass
@@ -802,11 +830,44 @@ class HarvestServingEngine:
         with tracing.span("kv.reload", **_block_args(bid, slot)):
             data = self.kv_mgr.read_payload(*bid)
             assert data is not None, f"reload of lost block {bid}"
-            with tracing.span("kv.write"):
-                self.pool_k = self.pool_k.at[:, slot].set(data[:, 0])
-                self.pool_v = self.pool_v.at[:, slot].set(data[:, 1])
+            # data: (L, K/V, bs, nkv, hd)
+            self._write([slot], data[:, :1], data[:, 1:], "reload")
             self.slot_req[slot] = self.row_of.get(bid[0], -1)
             self.slot_base[slot] = self.kv_mgr.table[bid].base_pos
+
+    def _write(self, slots: Sequence[int], k, v, path: str) -> None:
+        """One call of the in-place write program: ``k``/``v`` hold
+        ``len(slots)`` blocks in their last positions (``_write_blocks``);
+        a slot of ``n_slots`` skips its block."""
+        slots = np.asarray(slots, np.int32)
+        with tracing.span("kv.write", path=path,
+                          blocks=int(np.sum(slots < self.n_slots))):
+            self.pool_k, self.pool_v = _write_blocks(
+                self.pool_k, self.pool_v, slots, k, v)
+
+    def _place_and_write(self, r: Request, n: int, j0: int, k, v,
+                         path: str, allocated: bool = False) -> None:
+        """Give blocks ``j0..`` of ``r``'s ``n``-position prefix local
+        slots, then write all of them from ``k``/``v`` in one call.
+        ``allocated``: the blocks hold their slots already (a chunked
+        prefill's chunks allocated them).  The placements evict no block
+        of ``r`` (admission has pinned its blocks), so none is read out
+        before the write."""
+        nb = math.ceil(n / self.bs)
+        slots = [self.n_slots] * nb
+        for j in range(j0, nb):
+            if allocated:
+                slot = self.kv_mgr.table[(r.req_id, j)].local_slot
+            else:
+                slot, ops = self.kv_mgr.allocate_block(r.req_id, j,
+                                                       j * self.bs)
+                self._charge_writeback(ops)
+            slots[j] = slot
+            self.slot_req[slot] = r.row
+            self.slot_base[slot] = j * self.bs
+            self.kv_mgr.table[(r.req_id, j)].filled = min(
+                self.bs, n - j * self.bs)
+        self._write(slots, k, v, path)
 
     # ------------------------------------------------------------ states
     def _init_states(self):
@@ -956,11 +1017,9 @@ class HarvestServingEngine:
                 t += self._charge_critical(reload_ops)
                 self._charge_writeback(alloc_ops)
                 src = self.kv_mgr.store.table[ckey].local_slot
-                with tracing.span("kv.write"):
-                    self.pool_k = self.pool_k.at[:, slot].set(
-                        self.pool_k[:, src])
-                    self.pool_v = self.pool_v.at[:, slot].set(
-                        self.pool_v[:, src])
+                blk = [jax.lax.dynamic_slice_in_dim(pool, src, 1, axis=1)
+                       for pool in (self.pool_k, self.pool_v)]
+                self._write([slot], *blk, "cow")
             else:
                 t += self._charge_critical(
                     self.kv_mgr.adopt_block(r.req_id, j, ckey))
@@ -973,7 +1032,7 @@ class HarvestServingEngine:
 
     def _prefill_forward(self, prefix: List[int]):
         """One REAL forward over the (padded) prefix; returns
-        ``(logits, out, npre, n_pad)``.  Shared by the inline prefill and
+        ``(logits, out, npre)``.  Shared by the inline prefill and
         the final chunk of a chunked prefill — token fidelity comes from
         this single full-prefix forward in both paths."""
         n = len(prefix)
@@ -993,7 +1052,7 @@ class HarvestServingEngine:
             batch["positions_3d"] = jnp.broadcast_to(
                 jnp.arange(s_all)[:, None], (1, s_all, 3))
         logits, out = self._prefill_fn(self.params, batch)
-        return logits, out, npre, n_pad
+        return logits, out, npre
 
     def _prefill(self, r: Request) -> None:
         n = len(r.prompt) + len(r.output)
@@ -1005,7 +1064,7 @@ class HarvestServingEngine:
     def _prefill_inline(self, r: Request) -> None:
         prefix = r.prompt + r.output            # rollback re-prefills output
         n = len(prefix)
-        logits, out, npre, n_pad = self._prefill_forward(prefix)
+        logits, out, npre = self._prefill_forward(prefix)
         row = r.row
         # prefix-cache lookup: adopt (or COW-split) the longest cached
         # block chain BEFORE the prefill window — a hit's only cost is
@@ -1030,28 +1089,10 @@ class HarvestServingEngine:
             self._sync_clock()
 
         if self.L_kv:
-            k, v = out.kv
-            if npre:   # prefix embeddings occupy the first npre positions
-                k, v = k[:, :, npre:], v[:, :, npre:]
-            nb = math.ceil(n / self.bs)
             with tracing.span("engine.prefill.write",
-                              blocks=nb - len(matched)):
-                for j in range(len(matched), nb):
-                    slot, ops = self.kv_mgr.allocate_block(r.req_id, j,
-                                                           j * self.bs)
-                    self._charge_writeback(ops)
-                    lo, hi = j * self.bs, min((j + 1) * self.bs, n_pad)
-                    # the slices and the writes are eager dispatches,
-                    # each of which can hold the host behind the device
-                    with tracing.span("kv.write"):
-                        self.pool_k = self.pool_k.at[:, slot, :hi - lo].set(
-                            k[:, 0, lo:hi].astype(jnp.float32))
-                        self.pool_v = self.pool_v.at[:, slot, :hi - lo].set(
-                            v[:, 0, lo:hi].astype(jnp.float32))
-                    self.slot_req[slot] = row
-                    self.slot_base[slot] = j * self.bs
-                    ent = self.kv_mgr.table[(r.req_id, j)]
-                    ent.filled = min(self.bs, n - lo) if lo < n else 0
+                              blocks=math.ceil(n / self.bs) - len(matched)):
+                self._place_and_write(r, n, len(matched), *out.kv,
+                                      "prefill")
         if out.states is not None:
             self._set_state_row(row, out.states)
 
@@ -1136,26 +1177,12 @@ class HarvestServingEngine:
         TTFT is the end of the step window the chunk completed in."""
         prefix = r.prompt + r.output
         n = len(prefix)
-        logits, out, npre, n_pad = self._prefill_forward(prefix)
+        logits, out, npre = self._prefill_forward(prefix)
         row = r.row
         if self.L_kv:
-            k, v = out.kv
-            if npre:
-                k, v = k[:, :, npre:], v[:, :, npre:]
-            nb = math.ceil(n / self.bs)
-            for j in range(r.cached_prefix_blocks, nb):
-                # blocks were allocated chunk by chunk; fill payloads now
-                ent = self.kv_mgr.table[(r.req_id, j)]
-                slot = ent.local_slot
-                lo, hi = j * self.bs, min((j + 1) * self.bs, n_pad)
-                with tracing.span("kv.write"):
-                    self.pool_k = self.pool_k.at[:, slot, :hi - lo].set(
-                        k[:, 0, lo:hi].astype(jnp.float32))
-                    self.pool_v = self.pool_v.at[:, slot, :hi - lo].set(
-                        v[:, 0, lo:hi].astype(jnp.float32))
-                self.slot_req[slot] = row
-                self.slot_base[slot] = j * self.bs
-                ent.filled = min(self.bs, n - lo) if lo < n else 0
+            # blocks were allocated chunk by chunk; fill payloads now
+            self._place_and_write(r, n, r.cached_prefix_blocks, *out.kv,
+                                  "chunk", allocated=True)
         if out.states is not None:
             self._set_state_row(row, out.states)
         nxt = self._sample(np.asarray(logits[0, npre + n - 1]))
@@ -1218,7 +1245,7 @@ class HarvestServingEngine:
         te = self.runtime.transfers
         prefix = r.prompt + r.output
         n = len(prefix)
-        logits, out, npre, n_pad = self._prefill_forward(prefix)
+        logits, out, npre = self._prefill_forward(prefix)
         k = v = None
         if self.L_kv:
             kk, vv = out.kv
@@ -1314,22 +1341,8 @@ class HarvestServingEngine:
         n = job.n
         row = r.row
         if self.L_kv:
-            n_pad = job.k.shape[1]
-            nb = math.ceil(n / self.bs)
-            for j in range(nb):
-                slot, ops = self.kv_mgr.allocate_block(r.req_id, j,
-                                                       j * self.bs)
-                self._charge_writeback(ops)
-                lo, hi = j * self.bs, min((j + 1) * self.bs, n_pad)
-                with tracing.span("kv.write"):
-                    self.pool_k = self.pool_k.at[:, slot, :hi - lo].set(
-                        jnp.asarray(job.k[:, lo:hi]))
-                    self.pool_v = self.pool_v.at[:, slot, :hi - lo].set(
-                        jnp.asarray(job.v[:, lo:hi]))
-                self.slot_req[slot] = row
-                self.slot_base[slot] = j * self.bs
-                ent = self.kv_mgr.table[(r.req_id, j)]
-                ent.filled = min(self.bs, n - lo) if lo < n else 0
+            self._place_and_write(r, n, 0, job.k[:, None], job.v[:, None],
+                                  "stream")
         if job.states is not None:
             self._set_state_row(row, job.states)
         self.row_tokens[row] = r.output[-1]

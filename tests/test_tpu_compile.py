@@ -9,6 +9,7 @@ this file loads the TPU compiler.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from repro.configs import get_config
 from repro.kernels.harvest_copy.kernel import dequantize_reload, quantize_demote
 from repro.models import model as M
 from repro.models.params import _normal_leaf
+from repro.serving import engine as E
 
 #: one v5e chip's HBM (Google Cloud documentation, "TPU v5e")
 V5E_HBM_BYTES = 16 * 2**30
@@ -130,3 +132,63 @@ def test_decode_step_compiles_and_fits(one_chip, abstract_params):
     fn = jax.jit(lambda p, st: M.serve_step(p, st, CFG, None))
     compiled = fn.lower(abstract_params, _sds(state, one_chip)).compile()
     assert _bytes(compiled) < V5E_HBM_BYTES
+
+
+#: the cells' pool geometry: 8 rows over 640 slots of 16 positions
+CELL_BATCH, CELL_SLOTS = 8, 640
+
+
+def _cell_pool(sharding):
+    shape = (M.num_kv_layers(CFG), CELL_SLOTS, chip_smoke.BLOCK_SIZE,
+             CFG.num_kv_heads, CFG.resolved_head_dim)
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def _pool_copies(compiled, pool) -> list:
+    """Instructions of the compiled program that copy a whole pool."""
+    shape = f"f32[{','.join(map(str, pool.shape))}]"
+    out = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(", line)
+        if m and m.group(2).startswith("copy") and shape in m.group(1):
+            out.append(line[:160])
+    return out
+
+
+def _assert_in_place(compiled, pool):
+    assert not _pool_copies(compiled, pool), _pool_copies(compiled, pool)
+    # both pools (K and V) are aliased from input to output
+    pool_bytes = int(np.prod(pool.shape)) * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
+
+
+def test_decode_step_updates_pools_in_place(one_chip, abstract_params):
+    """The engine's decode step, its state donated, at the cells' pool
+    geometry: no copy of a pool, and both pools aliased in place."""
+    b, pool = CELL_BATCH, _cell_pool(one_chip)
+    i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
+    state = jax.eval_shape(lambda: M.DecodeState(
+        tokens=i32((b,)), pos=i32((b,)),
+        kv=M.KVPools(pool_k=jnp.zeros(pool.shape, pool.dtype),
+                     pool_v=jnp.zeros(pool.shape, pool.dtype),
+                     slot_req=i32((CELL_SLOTS,)),
+                     slot_base=i32((CELL_SLOTS,)),
+                     append_slot=i32((b,)), append_off=i32((b,))),
+        peer=None, states=None))
+    compiled = E._serve_step.lower(abstract_params, _sds(state, one_chip),
+                                   cfg=CFG, rules=None).compile()
+    _assert_in_place(compiled, pool)
+
+
+@pytest.mark.parametrize("positions", [16, 1024])
+def test_block_write_updates_pools_in_place(one_chip, positions):
+    """The engine's block write, its pools donated, for one block (a
+    reload) and for a 1,024-position prefill's 64 blocks in bf16."""
+    pool = _cell_pool(one_chip)
+    k = jax.ShapeDtypeStruct(
+        (pool.shape[0], 1, positions) + pool.shape[3:], jnp.bfloat16,
+        sharding=one_chip)
+    slots = jax.ShapeDtypeStruct((positions // chip_smoke.BLOCK_SIZE,),
+                                 jnp.int32, sharding=one_chip)
+    compiled = E._write_blocks.lower(pool, pool, slots, k, k).compile()
+    _assert_in_place(compiled, pool)
