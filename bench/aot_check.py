@@ -6,10 +6,11 @@
 For every cell (default: all in ``BENCHMARK.json``) this compiles, for
 one chip of a described ``v5e:2x2``, the weight draw, the decode step at
 the cell's batch and slot count, the prefill at the longest prompt the
-traffic sends, and the reference's layer and head at its width, then
-prints each program's memory analysis and the sum a run holds at once:
-weights, both pools twice (the decode step does not donate them), and
-the largest temporary.  Nothing runs, so it says nothing about time.
+traffic sends, and the programs of the reference the configuration names
+at its width (its ``lower``), then prints each program's memory analysis
+and the sum a run holds at once: weights, both pools twice (the decode
+step does not donate them), and the largest temporary.  Nothing runs,
+so it says nothing about time.
 """
 from __future__ import annotations
 
@@ -29,9 +30,8 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench.harness import spec, weights
+    from bench.harness import check, spec, weights
     from bench.harness.traffic import Traffic
-    from bench.reference import llama
     from bench.run import program_config
     from repro.launch import serve as serve_cli
     from repro.models import model as M
@@ -96,22 +96,17 @@ def main(argv=None) -> int:
                 abstract, batch, cfg=cfg, want_kv=True).compile())
 
         width = 128 * -(-traffic.max_sequence() // 128)
-        conf = llama.frozen(cell.config)
-        x = jax.ShapeDtypeStruct((2, width, cfg.d_model), jnp.float32,
-                                 sharding=one)
-        lay = report(f"reference layer, 2 x {width}", llama._layer.lower(
-            x, abstract["layers"], jax.ShapeDtypeStruct(
-                (), jnp.int32, sharding=one), cfg=conf,
-            precision="f32").compile())
-        head = report("reference head", llama._head.lower(
-            x, abstract["final_norm"], abstract["lm_head"],
-            i32(2, width), cfg=conf, precision="f32").compile())
+        ref = check.Reference.of(cell)
+        refs = [report(label, lowered.compile()) for label, lowered in
+                ref.module.lower(abstract, ref.cfg, i32(2, width))]
 
         pools = 2 * pool.size * 4
         serving = w_bytes + 2 * pools + max(dec.temp_size_in_bytes,
                                             pre.temp_size_in_bytes)
-        checking = w_bytes + max(lay.temp_size_in_bytes,
-                                 head.temp_size_in_bytes) + 2 * x.size * 4
+        # the weights, the largest temporary, and the activations a
+        # program reads and writes
+        checking = (w_bytes + max(m.temp_size_in_bytes for m in refs)
+                    + 2 * max(m.output_size_in_bytes for m in refs))
         print(f"  serving holds ~{serving / GiB:.3f} GiB (weights "
               f"{w_bytes / GiB:.3f}, pools {pools / GiB:.3f} twice), the "
               f"reference ~{checking / GiB:.3f} GiB, of {hbm / GiB:.0f} GiB")

@@ -37,9 +37,9 @@ def _emit(obj: dict) -> None:
 def control(cell, seeds, seconds, run_cell) -> None:
     from bench.harness import check
 
-    def after(weights, sample, ref_cfg, _run):
-        top = check.control_tokens(weights, sample, ref_cfg)
-        return {"control_gap": check.widest_gap(weights, sample, ref_cfg,
+    def after(weights, sample, ref, _run):
+        top = check.control_tokens(weights, sample, ref)
+        return {"control_gap": check.widest_gap(weights, sample, ref,
                                                 probe=top)}
 
     for seed in seeds:
@@ -62,7 +62,7 @@ def sweep(cell, seed, rates, seconds, run_cell) -> None:
                "metrics": r["metrics"], **r["calibration"]})
 
 
-def _backlog(_weights, _sample, _ref_cfg, run) -> dict:
+def _backlog(_weights, _sample, _ref, run) -> dict:
     """What a sweep reads beside the tails: requests due in the window
     and how many of them had not finished when it closed."""
     from bench.harness import readers

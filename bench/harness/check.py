@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``, for a served model.
 
 Once the window has closed, a sample of the requests the window finished
-is drawn from the seed, the longest among them.  The plain reference runs
+is drawn from the seed, the longest among them.  The plain reference that
+the cell's configuration names (``bench/reference/<module>.py``) runs
 once over each prompt with its served tokens, and the number compared is
 the widest gap by which a served token's logit lies below the
 reference's best logit at that position.  Greedy decoding serves the
@@ -12,7 +13,8 @@ reference computed in float8 reads (``bench/calibrate.py``).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Sequence
+from types import ModuleType
+from typing import Any, List, Sequence
 
 import numpy as np
 
@@ -29,6 +31,21 @@ class Sample:
     mask: np.ndarray        # (n, width) bool: positions that served one
     requests: int
     served_tokens: int
+
+
+@dataclass(frozen=True)
+class Reference:
+    """A cell's plain reference: the module its configuration names, and
+    the configuration as that module takes it."""
+    module: ModuleType
+    cfg: Any
+
+    @classmethod
+    def of(cls, cell) -> "Reference":
+        return cls(cell.reference, cell.reference.frozen(cell.config))
+
+    def gaps(self, weights, tokens, probe, precision: str):
+        return self.module.gaps(weights, tokens, probe, self.cfg, precision)
 
 
 def draw_sample(turns: Sequence, seed: int, width: int) -> Sample:
@@ -67,13 +84,12 @@ def draw_sample(turns: Sequence, seed: int, width: int) -> Sample:
     return Sample(toks, served, mask, len(picked), int(mask.sum()))
 
 
-def widest_gap(weights, sample: Sample, cfg, probe=None,
+def widest_gap(weights, sample: Sample, ref: Reference, probe=None,
                precision: str = "f32", block: int = 2) -> float:
     """The widest reference-logit gap of ``probe``'s tokens (default: the
     served ones) at the sampled positions; the reference runs over
     ``block`` sequences at a time."""
     import jax.numpy as jnp
-    from bench.reference import llama
     probe = sample.served if probe is None else probe
     worst = 0.0
     for lo in range(0, sample.requests, block):
@@ -84,27 +100,26 @@ def widest_gap(weights, sample: Sample, cfg, probe=None,
             toks, pr = (np.concatenate([a, np.repeat(a[:1], pad, 0)])
                         for a in (toks, pr))
             m = np.concatenate([m, np.zeros((pad,) + m.shape[1:], bool)])
-        gap, _ = llama.gaps(weights, jnp.asarray(toks), jnp.asarray(pr), cfg,
-                            precision)
+        gap, _ = ref.gaps(weights, jnp.asarray(toks), jnp.asarray(pr),
+                          precision)
         g = np.asarray(gap)[m]
         if g.size:
             worst = max(worst, float(g.max()))
     return worst
 
 
-def control_tokens(weights, sample: Sample, cfg, block: int = 2
+def control_tokens(weights, sample: Sample, ref: Reference, block: int = 2
                    ) -> np.ndarray:
     """At each sampled position, the token the float8 reference ranks
     first (the control)."""
     import jax.numpy as jnp
-    from bench.reference import llama
     out = np.zeros_like(sample.served)
     for lo in range(0, sample.requests, block):
         toks = sample.tokens[lo:lo + block]
         pad = block - len(toks)
         if pad:
             toks = np.concatenate([toks, np.repeat(toks[:1], pad, 0)])
-        _, top = llama.gaps(weights, jnp.asarray(toks),
-                            jnp.zeros(toks.shape, jnp.int32), cfg, "fp8")
+        _, top = ref.gaps(weights, jnp.asarray(toks),
+                          jnp.zeros(toks.shape, jnp.int32), "fp8")
         out[lo:lo + block] = np.asarray(top)[:block - pad]
     return out

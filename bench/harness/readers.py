@@ -101,8 +101,8 @@ def decode_roofline(run: Run) -> Optional[float]:
     if not calls or not steps:
         return None
     least: List[float] = [R.least_seconds(
-        R.decode_flops(run.dims, d.rows, d.attended),
-        R.decode_bytes(run.dims, d.rows, d.attended), run.peak)
+        run.counts.decode_flops(d.rows, d.attended),
+        run.counts.decode_bytes(d.rows, d.attended), run.peak)
         for d in steps]
     return 100.0 * fmean(least) / fmean(calls)
 
@@ -119,9 +119,9 @@ def mfu(run: Run, whole_window: bool) -> Optional[float]:
     time the server had work, or the whole window, at the chip's peak."""
     if run.peak is None:
         return None
-    flops = sum(R.prefill_flops(run.dims, p.cached, p.tokens - p.cached)
+    flops = sum(run.counts.prefill_flops(p.cached, p.tokens - p.cached)
                 for p in run.prefills if run.inside(p.t))
-    flops += sum(R.decode_flops(run.dims, d.rows, d.attended)
+    flops += sum(run.counts.decode_flops(d.rows, d.attended)
                  for d in run.decodes if run.inside(d.t))
     seconds = run.seconds if whole_window else run.busy_s()
     if not flops or not seconds:

@@ -5,7 +5,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from bench.harness.client import Decode, Prefill, Turn
-from bench.harness.roofline import Dims
+from bench.harness.roofline import Counts
 from bench.harness.trace import Trace
 
 
@@ -16,7 +16,8 @@ class Run:
     t_end: float
     setup_s: float
     max_batch: int
-    dims: Dims
+    #: the least work of a step, from the configuration's reference
+    counts: Counts
     peak: Optional[dict]
     turns: List[Turn]
     prefills: List[Prefill]

@@ -1,7 +1,9 @@
 """Operations and bytes a step needs, from the configuration's sizes.
 
-These are the least any implementation needs, so no change to the
-program can push a share computed from them past 100%:
+Each reference module's ``counts(conf)`` gives them for its architecture;
+:class:`DenseCounts` holds those of the dense decoder.  These are the
+least any implementation needs, so no change to the program can push a
+share computed from them past 100%:
 
 - weights are read once per step in the served type (bf16, 2 bytes);
 - keys and values are read once per live position attended, at 2 bytes
@@ -14,12 +16,27 @@ program can push a share computed from them past 100%:
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Protocol
 
 ELEM_BYTES = 2
 
 
+class Counts(Protocol):
+    """The least work of one architecture's steps, as its reference module's
+    ``counts(conf)`` returns it (``bench/reference/__init__.py``)."""
+
+    def decode_flops(self, rows: int, attended: int) -> int: ...
+
+    def decode_bytes(self, rows: int, attended: int) -> int: ...
+
+    def prefill_flops(self, cached: int, new: int) -> int: ...
+
+
 @dataclass(frozen=True)
-class Dims:
+class DenseCounts:
+    """The counts of a dense decoder layer: attention with grouped K/V
+    heads and a gated MLP."""
+
     layers: int
     d_model: int
     heads: int
@@ -29,7 +46,7 @@ class Dims:
     vocab: int
 
     @classmethod
-    def from_config(cls, c: dict) -> "Dims":
+    def from_config(cls, c: dict) -> "DenseCounts":
         return cls(c["num_layers"], c["d_model"], c["num_heads"],
                    c["num_kv_heads"], c["head_dim"], c["d_ff"],
                    c["vocab_size"])
@@ -61,29 +78,27 @@ class Dims:
         return (ELEM_BYTES * 2 * self.layers * self.kv_heads * self.head_dim
                 * positions)
 
+    def decode_flops(self, rows: int, attended: int) -> int:
+        """One decode step of ``rows`` queries over ``attended`` live
+        positions in all (each row's own, its new token included)."""
+        return (2 * rows * (self.layers * self.layer_matmul_params
+                            + self.head_params)
+                + self.attn_flops(attended))
 
-def decode_flops(d: Dims, rows: int, attended: int) -> int:
-    """One decode step of ``rows`` queries over ``attended`` live
-    positions in all (each row's own, its new token included)."""
-    return (2 * rows * (d.layers * d.layer_matmul_params + d.head_params)
-            + d.attn_flops(attended))
+    def decode_bytes(self, rows: int, attended: int) -> int:
+        return (self.weight_bytes + ELEM_BYTES * rows * self.d_model
+                + self.kv_bytes(attended))
 
-
-def decode_bytes(d: Dims, rows: int, attended: int) -> int:
-    return (d.weight_bytes + ELEM_BYTES * rows * d.d_model
-            + d.kv_bytes(attended))
-
-
-def prefill_flops(d: Dims, cached: int, new: int) -> int:
-    """A prefill of ``new`` positions after ``cached`` served ones: the
-    layers for each new position, causal attention over what precedes
-    it, and the output head for the last position."""
-    if new <= 0:
-        return 0
-    # positions cached+1 .. cached+new attended by the new queries
-    attended = new * cached + new * (new + 1) // 2
-    return (2 * new * d.layers * d.layer_matmul_params + 2 * d.head_params
-            + d.attn_flops(attended))
+    def prefill_flops(self, cached: int, new: int) -> int:
+        """A prefill of ``new`` positions after ``cached`` served ones: the
+        layers for each new position, causal attention over what precedes
+        it, and the output head for the last position."""
+        if new <= 0:
+            return 0
+        # positions cached+1 .. cached+new attended by the new queries
+        attended = new * cached + new * (new + 1) // 2
+        return (2 * new * self.layers * self.layer_matmul_params
+                + 2 * self.head_params + self.attn_flops(attended))
 
 
 def least_seconds(flops: float, nbytes: float, peak: dict) -> float:

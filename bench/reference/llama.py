@@ -11,6 +11,9 @@ one layer at a time, so the reference fits beside them.
 ``precision="fp8"`` is the control: every matmul operand is rounded to
 float8 e4m3 first (accumulation stays float32), the step below the
 bf16 the configuration serves in.
+
+Its work counts are the dense decoder's, ``bench.harness.roofline``'s
+:class:`~bench.harness.roofline.DenseCounts`.
 """
 from __future__ import annotations
 
@@ -18,6 +21,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from bench.harness.roofline import DenseCounts
 
 F32 = jnp.float32
 NEG = -1e30
@@ -110,6 +115,27 @@ def gaps(weights, tokens, probe, cfg: dict, precision: str = "f32"):
                    precision=precision)
     return _head(x, weights["final_norm"], weights["lm_head"], probe,
                  cfg=cfg, precision=precision)
+
+
+def counts(conf: dict) -> DenseCounts:
+    """The least work of a step, from the configuration file's sizes."""
+    return DenseCounts.from_config(conf)
+
+
+def lower(weights, cfg, tokens) -> list:
+    """The layer and the head :func:`gaps` runs, lowered for abstract
+    ``weights`` and ``tokens`` (b, s), each on ``tokens``' sharding."""
+    b, s = tokens.shape
+    x = jax.ShapeDtypeStruct((b, s, dict(cfg)["d_model"]), F32,
+                             sharding=tokens.sharding)
+    i = jax.ShapeDtypeStruct((), jnp.int32, sharding=tokens.sharding)
+    return [
+        (f"reference layer, {b} x {s}", _layer.lower(
+            x, weights["layers"], i, cfg=cfg, precision="f32")),
+        ("reference head", _head.lower(
+            x, weights["final_norm"], weights["lm_head"], tokens, cfg=cfg,
+            precision="f32")),
+    ]
 
 
 class frozen(dict):

@@ -10,8 +10,8 @@ device, builds the server through the program's own construction
 (``repro.launch.serve.build_server``), warms every shape the traffic can
 use, serves the traffic's prebuilt state, then measures for ``--seconds``
 on the wall clock.  Once the window has closed, a seed-drawn sample of
-the finished requests is compared with the plain reference in
-``bench/reference``.
+the finished requests is compared with the plain reference the
+configuration names (``bench/reference/<module>.py``).
 
 Progress and counts go to standard error, ending with each number
 compared beside its limit; the last line of standard output is the
@@ -95,17 +95,47 @@ class CompileCounter:
 
 def program_config(spec: dict):
     """The program's ``ModelConfig`` for a configuration file: the named
-    architecture with every size the file gives."""
+    architecture with every size the file gives.  A key whose field holds a
+    sub-config (``moe``, ``ssm``, ...) takes an object of that sub-config's
+    fields, applied over the architecture's own sub-config, or building one
+    where it has none."""
     import dataclasses
+    import typing
     from repro.configs import get_config
+    from repro.configs.base import ModelConfig
     base = get_config(spec["arch"])
-    keys = {f.name for f in dataclasses.fields(base)}
-    cfg = dataclasses.replace(base, **{k: v for k, v in spec.items()
-                                       if k in keys and k != "name"})
+    hints = typing.get_type_hints(ModelConfig)
+    changes = {}
+    for f in dataclasses.fields(base):
+        if f.name not in spec or f.name == "name":
+            continue
+        v = spec[f.name]
+        sub = [t for t in typing.get_args(hints[f.name])
+               if dataclasses.is_dataclass(t)]
+        if sub and isinstance(v, dict):
+            v = _sub_config(spec["name"], f.name, sub[0],
+                            getattr(base, f.name), v)
+        changes[f.name] = v
+    cfg = dataclasses.replace(base, **changes)
     if cfg.resolved_head_dim != spec["head_dim"]:
         raise ValueError(f"{spec['name']}: head_dim {spec['head_dim']} but "
                          f"the program resolves {cfg.resolved_head_dim}")
     return cfg
+
+
+def _sub_config(config: str, key: str, cls, current, given: dict):
+    """``given`` over ``current`` (or a new ``cls`` where that is None),
+    lists as tuples so that the config stays hashable."""
+    import dataclasses
+    known = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(given) - known)
+    if unknown:
+        raise ValueError(f"{config}: {key} has no field "
+                         f"{', '.join(unknown)} (it has {sorted(known)})")
+    given = {k: tuple(v) if isinstance(v, list) else v
+             for k, v in given.items()}
+    return (cls(**given) if current is None
+            else dataclasses.replace(current, **given))
 
 
 def device_info(jax, cell, peaks, require_chip: bool):
@@ -168,15 +198,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
              require_chip: bool = True, after=None) -> Optional[dict]:
     """One run of ``cell``; returns the result line's object, or ``None``
     (after saying why) where the devices do not fit the cell.  ``after``,
-    if given, is called as ``after(weights, sample, ref_cfg, run)`` once the
+    if given, is called as ``after(weights, sample, ref, run)`` once the
     comparison is made, and what it returns is kept under
     ``"calibration"`` (``bench/calibrate.py``)."""
     import jax
     from bench.harness import check, readers, record, spec, weights
     from bench.harness import client as clients
-    from bench.harness.roofline import Dims
     from bench.harness.traffic import Traffic
-    from bench.reference.llama import frozen
 
     if not __import__("os").environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
@@ -259,7 +287,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
 
     run = record.Run(
         seconds=seconds, t0=t0, t_end=t_end, setup_s=setup_s,
-        max_batch=args.max_batch, dims=Dims.from_config(conf), peak=peak,
+        max_batch=args.max_batch, counts=cell.reference.counts(conf),
+        peak=peak,
         turns=client.turns, prefills=client.prefills,
         decodes=client.decodes, steps=client.steps, hook_s=client.hook_s,
         hook_blocks=client.hook_blocks, kv=dict(kv), trace=tr,
@@ -298,9 +327,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     client.server = client.eng = None
     runtime = server = eng = client = None
     gc.collect()
-    ref_cfg = frozen(conf)
+    ref = check.Reference.of(cell)
     t_ref = time.perf_counter()
-    gap = check.widest_gap(params, sample, ref_cfg)
+    gap = check.widest_gap(params, sample, ref)
     log(f"reference: {sample.requests} requests, {sample.served_tokens} "
         f"served tokens in {time.perf_counter() - t_ref:.3f} s")
     limit = float(srv["checks"]["logit_gap"])
@@ -323,7 +352,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
             "device_ops": [[n, s] for n, s in T.op_seconds(tr)[:10]],
             "idle_gaps": [[n, s] for n, s in T.idle_gaps(tr)[:10]]}
     if after is not None:
-        result["calibration"] = after(params, sample, ref_cfg, run)
+        result["calibration"] = after(params, sample, ref, run)
     result["checks"] = checks
     log(f"check logit_gap {gap!r} limit {limit!r} (at most)")
     log(f"check tokens_compared {sample.served_tokens} limit "

@@ -12,9 +12,9 @@ CELLS = [w["name"] for w in json.load(open(spec.ROOT / "BENCHMARK.json"))
          ["workloads"]]
 
 
-def _control(weights, sample, ref_cfg, _run):
-    top = check.control_tokens(weights, sample, ref_cfg)
-    return check.widest_gap(weights, sample, ref_cfg, probe=top)
+def _control(weights, sample, ref, _run):
+    top = check.control_tokens(weights, sample, ref)
+    return check.widest_gap(weights, sample, ref, probe=top)
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -26,14 +26,20 @@ def _alter_tokens(monkeypatch):
 
 
 def _wrap_step(monkeypatch, change):
+    """``change(logits, old, new)`` on each decode step's result; ``old``
+    holds a copy of the KV pools the step was given, since the step takes
+    its state donated and deletes them."""
+    import jax
+    import jax.numpy as jnp
     import repro.serving.engine as E
     orig = E._serve_step
     calls = [0]
 
     def broken(params, state, **kw):
+        old = state._replace(kv=jax.tree.map(jnp.copy, state.kv))
         logits, new = orig(params, state, **kw)
         calls[0] += 1
-        return change(logits, state, new)
+        return change(logits, old, new)
     monkeypatch.setattr(E, "_serve_step", broken)
     return calls
 

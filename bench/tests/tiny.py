@@ -5,14 +5,18 @@ positions and answers of 64, and a peer budget of a few blocks so that
 the tiers still move blocks."""
 from __future__ import annotations
 
+from typing import Optional
+
 from bench.harness import spec
 
 TINY = dict(num_layers=2, d_model=1024, num_heads=8, d_ff=1024,
             vocab_size=2048)
 
 
-def tiny_cell(name: str):
-    cell = spec.load_cell(name)
+def tiny_cell(name: str, benchmark: Optional[dict] = None):
+    """The cell ``name`` of ``benchmark`` (default: ``BENCHMARK.json``),
+    cut to the tiny size."""
+    cell = spec.load_cell(name, benchmark)
     cell.config = dict(cell.config, **TINY,
                        num_kv_heads=max(1, cell.config["num_kv_heads"] // 4),
                        sliding_window=64 if cell.config.get("sliding_window")
