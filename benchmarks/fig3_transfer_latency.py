@@ -12,7 +12,7 @@ from __future__ import annotations
 from pathlib import Path
 
 from benchmarks.common import Check, fmt_table, save_result
-from repro.configs import PAPER_ARCHS, get_config
+from repro.configs import TABLE1_ARCHS, get_config
 from repro.core.tiers import H100_NVLINK, expert_bytes
 
 
@@ -30,7 +30,7 @@ def run(out_dir: Path) -> dict:
 
     # expert-size markers for the paper's four MoE models
     models = []
-    for arch in PAPER_ARCHS:
+    for arch in TABLE1_ARCHS:
         cfg = get_config(arch)
         eb = expert_bytes(cfg)
         th = hw.host_link.transfer_time(eb)

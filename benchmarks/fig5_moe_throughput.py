@@ -18,7 +18,7 @@ from __future__ import annotations
 from pathlib import Path
 
 from benchmarks.common import Check, fmt_table, save_result
-from repro.configs import PAPER_ARCHS, get_config
+from repro.configs import TABLE1_ARCHS, get_config
 from repro.core.runtime import HarvestRuntime
 from repro.core.simulator import AccessModelConfig, simulate_moe_decode
 from repro.core.tiers import H100_NVLINK
@@ -43,7 +43,7 @@ def run(out_dir: Path, trials: int = TRIALS,
     runtime = HarvestRuntime(hardware=hw)
     rows, out_rows = [], []
     gains = {}
-    for arch in PAPER_ARCHS:
+    for arch in TABLE1_ARCHS:
         cfg = get_config(arch)
         peer_tps, host_tps = [], []
         for t in range(trials):

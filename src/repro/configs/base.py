@@ -21,7 +21,13 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts FFN configuration."""
+    """Mixture-of-experts FFN configuration.
+
+    ``num_experts`` is the router's width: the experts of the whole layer.
+    Under expert parallelism a chip holds ``held`` of them, experts
+    ``first_held .. first_held + held - 1``, and computes their part of
+    the layer's output; 0 holds them all.
+    """
 
     num_experts: int
     top_k: int
@@ -35,6 +41,41 @@ class MoEConfig:
     lb_loss_weight: float = 0.01
     # dispatch capacity factor (tokens_per_expert = t*k/E * cf)
     capacity_factor: float = 1.25
+    held: int = 0
+    first_held: int = 0
+
+    @property
+    def num_held(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.held or self.num_experts
+
+
+@dataclass(frozen=True)
+class MixedAttentionConfig:
+    """Windowed and full attention in one stack (Mellum2).
+
+    Layer ``i`` is of kind ``layer_types[i % len(layer_types)]``.
+    ``"sliding_attention"`` layers attend within the model's
+    ``sliding_window`` with the default RoPE at its ``rope_theta``;
+    ``"full_attention"`` layers attend to every position with RoPE at
+    ``full_rope_theta``, YaRN-scaled (arXiv:2309.00071) where
+    ``yarn_factor`` exceeds 1.  ``yarn_attention_factor`` 0 takes YaRN's
+    own ``0.1 ln(factor) + 1``.
+    """
+
+    layer_types: Tuple[str, ...] = ("full_attention",)
+    full_rope_theta: float = 10_000.0
+    yarn_factor: float = 1.0
+    yarn_original_max_position_embeddings: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 0.0
+
+    def __post_init__(self):
+        unknown = set(self.layer_types) - {"sliding_attention",
+                                           "full_attention"}
+        if unknown:
+            raise ValueError(f"unknown layer types {sorted(unknown)}")
 
 
 @dataclass(frozen=True)
@@ -128,8 +169,16 @@ class ModelConfig:
     hybrid: Optional[HybridConfig] = None
     xlstm: Optional[XLSTMConfig] = None
     modality: Optional[ModalityConfig] = None
+    mixed_attention: Optional[MixedAttentionConfig] = None
 
     dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if self.mixed_attention is not None and (
+                self.family not in ("dense", "moe") or self.parallel_block
+                or (self.moe is not None and self.moe.layer_period != 1)):
+            raise ValueError(f"{self.name}: mixed attention needs one "
+                             f"stack of sequential dense or MoE blocks")
 
     # ------------------------------------------------------------------
     # Derived helpers
@@ -152,6 +201,12 @@ class ModelConfig:
         if self.moe is None:
             return 0
         return sum(self.is_moe_layer(i) for i in range(self.num_layers))
+
+    def layer_type(self, layer_idx: int) -> str:
+        """A mixed-attention stack's layer kind: ``"sliding_attention"``
+        or ``"full_attention"``."""
+        types = self.mixed_attention.layer_types
+        return types[layer_idx % len(types)]
 
     @property
     def supports_long_context(self) -> bool:
@@ -198,7 +253,7 @@ class ModelConfig:
                 layer_active += attn
                 if self.is_moe_layer(i):
                     e = ffn_params(self.moe.d_ff_expert)
-                    layer_total += self.moe.num_experts * e
+                    layer_total += self.moe.num_held * e
                     layer_active += self.moe.top_k * e
                     if self.moe.num_shared_experts:
                         s = ffn_params(self.moe.d_ff_shared) * self.moe.num_shared_experts
@@ -221,7 +276,8 @@ class ModelConfig:
     # Reduced variant for CPU smoke tests
     # ------------------------------------------------------------------
     def reduced(self) -> "ModelConfig":
-        """Same family, tiny dims: 2 layers, d_model<=512, <=4 experts."""
+        """Same family, tiny dims: 2 layers, d_model<=512, <=4 experts; a
+        mixed-attention stack keeps one layer of each kind."""
         d_model = min(self.d_model, 256)
         num_heads = min(self.num_heads, 4)
         num_kv_heads = min(self.num_kv_heads, max(1, num_heads // self.q_per_kv if self.q_per_kv else num_heads))
@@ -249,7 +305,13 @@ class ModelConfig:
                 d_ff_shared=min(self.moe.d_ff_shared, 256),
                 layer_period=1,
                 capacity_factor=8.0,   # lossless dispatch for exactness tests
+                held=min(self.moe.held, 4),
+                first_held=0,
             )
+        if self.mixed_attention is not None:
+            kinds = tuple(dict.fromkeys(self.mixed_attention.layer_types))
+            changes["mixed_attention"] = dataclasses.replace(
+                self.mixed_attention, layer_types=kinds)
         if self.ssm is not None:
             changes["ssm"] = dataclasses.replace(
                 self.ssm, state_dim=min(self.ssm.state_dim, 16), head_dim=32,
@@ -302,8 +364,10 @@ ASSIGNED_ARCHS = [
     "dbrx-132b",
 ]
 
-# the paper's own MoE zoo (Table 1) used by the Fig 5/6 benchmarks
-PAPER_ARCHS = ["mixtral-8x7b", "qwen2-moe", "phi-3.5-moe", "phi-tiny-moe"]
+# the paper's own MoE zoo (Table 1) used by the Fig 3/5 benchmarks
+TABLE1_ARCHS = ["mixtral-8x7b", "qwen2-moe", "phi-3.5-moe", "phi-tiny-moe"]
+# MoE models served beside the assigned ones: Table 1's, and Mellum2
+PAPER_ARCHS = TABLE1_ARCHS + ["mellum2-12b-a2.5b"]
 
 
 def _module_name(arch_id: str) -> str:

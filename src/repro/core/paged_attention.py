@@ -36,8 +36,10 @@ class Partials(NamedTuple):
 
 
 def pool_partials(q, pool_k, pool_v, slot_req, slot_base, q_pos,
-                  cfg: ModelConfig) -> Partials:
-    """Flash-decode partials of one pool (tier) on one shard."""
+                  cfg: ModelConfig, window=None) -> Partials:
+    """Flash-decode partials of one pool (tier) on one shard.  ``window``
+    (the layer's own in a mixed stack, possibly traced) replaces the
+    model's ``sliding_window``."""
     b, nq, hd = q.shape
     n_slots, bs, nkv, _ = pool_k.shape
     gq = nq // nkv
@@ -60,8 +62,10 @@ def pool_partials(q, pool_k, pool_v, slot_req, slot_base, q_pos,
     pos = slot_base[:, None] + jnp.arange(bs, dtype=jnp.int32)[None, :]
     qp = jnp.take(q_pos, req, axis=0)[:, None]               # (n, 1)
     valid = (slot_req[:, None] >= 0) & (pos <= qp) & (pos >= 0)
-    if cfg.sliding_window is not None:
-        valid &= pos > (qp - cfg.sliding_window)
+    if window is None:
+        window = cfg.sliding_window
+    if window is not None:
+        valid &= pos > (qp - window)
     if cfg.attention_chunk is not None:
         valid &= (pos // cfg.attention_chunk) == (qp // cfg.attention_chunk)
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
@@ -141,7 +145,8 @@ def append_kv(pool_k, pool_v, k_new, v_new, local_slot, offset):
 
 
 def paged_decode_attention(q, pools, q_pos, cfg: ModelConfig,
-                           axis_names: Sequence[str] = ()) -> jnp.ndarray:
+                           axis_names: Sequence[str] = (),
+                           window=None) -> jnp.ndarray:
     """Attention of one decode token against the union of KV pools.
 
     ``pools`` is a sequence of (pool_k, pool_v, slot_req, slot_base) tuples —
@@ -149,7 +154,7 @@ def paged_decode_attention(q, pools, q_pos, cfg: ModelConfig,
     before the step) or [local, peer] (in-place mode: the harvested tier joins
     the softmax directly).
     """
-    parts = [pool_partials(q, pk, pv, sr, sb, q_pos, cfg)
+    parts = [pool_partials(q, pk, pv, sr, sb, q_pos, cfg, window)
              for (pk, pv, sr, sb) in pools]
     merged = merge_partials(parts)
     return finalize(merged, axis_names)

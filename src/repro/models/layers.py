@@ -9,10 +9,12 @@ and ``repro/kernels/paged_attention``.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
@@ -54,16 +56,23 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+def _rotate(x, angles, scale=None):
+    """Rotate-half RoPE of x: (..., heads, head_dim) by ``angles``
+    (..., 1, head_dim/2), cos and sin times ``scale`` where given."""
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if scale is not None:
+        sin, cos = sin * scale, cos * scale
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
 def apply_rope(x, positions, theta: float):
     """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
     hd = x.shape[-1]
     freqs = rope_freqs(hd, theta)                          # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
-    angles = angles[..., None, :]                          # broadcast over heads
-    sin, cos = jnp.sin(angles), jnp.cos(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    return _rotate(x, angles[..., None, :])                # broadcast over heads
 
 
 def apply_mrope(x, positions_3d, theta: float, sections):
@@ -85,14 +94,78 @@ def apply_mrope(x, positions_3d, theta: float, sections):
         jnp.broadcast_to(sec, positions_3d.shape[:-1] + (hd // 2,)).astype(jnp.int32),
         axis=-1,
     )                                                      # (..., seq, hd/2)
-    angles = (pos * freqs)[..., None, :]
-    sin, cos = jnp.sin(angles), jnp.cos(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    return _rotate(x, (pos * freqs)[..., None, :])
 
 
-def position_embedding(x, positions, cfg: ModelConfig, positions_3d=None):
+# ---------------------------------------------------------------------------
+# Mixed attention: what differs by layer, as data the layer scan indexes
+# ---------------------------------------------------------------------------
+
+#: a full-attention layer's window in a mixed stack: past any position
+FULL_WINDOW = 2 ** 30
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float, beta_slow: float
+                  ) -> np.ndarray:
+    """YaRN's inverse frequencies (arXiv:2309.00071): interpolated by
+    ``factor`` below ``beta_slow`` rotations over ``original_max``
+    positions, kept above ``beta_fast``, blended linearly between, the
+    correction range rounded outwards (transformers'
+    ``_compute_yarn_parameters``)."""
+    def dim_of(rotations):
+        return (head_dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    pos = theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    return (1.0 / (factor * pos)) * ramp + (1.0 / pos) * (1.0 - ramp)
+
+
+class LayerKind(NamedTuple):
+    """One layer's attention in a mixed stack, as traced values; stacked
+    over the layers, the table :func:`layer_kinds` builds."""
+    window: jnp.ndarray      # () int32: positions a query attends back
+    inv_freq: jnp.ndarray    # (head_dim/2,) f32 RoPE inverse frequencies
+    rope_scale: jnp.ndarray  # () f32: YaRN's attention factor on cos, sin
+
+
+def layer_kinds(cfg: ModelConfig) -> Optional[LayerKind]:
+    """The per-layer table of a mixed-attention stack (leading dim
+    ``num_layers``), or None for a stack whose layers are all alike."""
+    ma = cfg.mixed_attention
+    if ma is None:
+        return None
+    hd = cfg.resolved_head_dim
+    slots = np.arange(0, hd, 2, dtype=np.float32) / hd
+    sliding = (1.0 / cfg.rope_theta ** slots, 1.0,
+               cfg.sliding_window or FULL_WINDOW)
+    if ma.yarn_factor > 1:
+        full = (yarn_inv_freq(hd, ma.full_rope_theta, ma.yarn_factor,
+                              ma.yarn_original_max_position_embeddings,
+                              ma.yarn_beta_fast, ma.yarn_beta_slow),
+                ma.yarn_attention_factor
+                or 0.1 * math.log(ma.yarn_factor) + 1.0, FULL_WINDOW)
+    else:
+        full = (1.0 / ma.full_rope_theta ** slots, 1.0, FULL_WINDOW)
+    rows = [sliding if cfg.layer_type(i) == "sliding_attention" else full
+            for i in range(cfg.num_layers)]
+    return LayerKind(
+        window=jnp.asarray([r[2] for r in rows], jnp.int32),
+        inv_freq=jnp.asarray(np.stack([r[0] for r in rows]), jnp.float32),
+        rope_scale=jnp.asarray([r[1] for r in rows], jnp.float32))
+
+
+def position_embedding(x, positions, cfg: ModelConfig, positions_3d=None,
+                       kind: Optional[LayerKind] = None):
+    if kind is not None:
+        angles = positions[..., None].astype(jnp.float32) * kind.inv_freq
+        return _rotate(x, angles[..., None, :], kind.rope_scale)
     if cfg.rope_style == "rope":
         return apply_rope(x, positions, cfg.rope_theta)
     if cfg.rope_style == "mrope":
@@ -118,11 +191,14 @@ def sinusoidal_positions(positions, d_model: int):
 # ---------------------------------------------------------------------------
 
 
-def _band_mask(q_pos, k_pos, cfg: ModelConfig):
-    """(q, k) boolean mask combining causal + sliding-window + chunked-local."""
+def _band_mask(q_pos, k_pos, cfg: ModelConfig, window=None):
+    """(q, k) boolean mask combining causal + sliding-window + chunked-local.
+    ``window`` (a layer's own, possibly traced) replaces the model's."""
     m = k_pos[None, :] <= q_pos[:, None]                   # causal
-    if cfg.sliding_window is not None:
-        m &= k_pos[None, :] > (q_pos[:, None] - cfg.sliding_window)
+    if window is None:
+        window = cfg.sliding_window
+    if window is not None:
+        m &= k_pos[None, :] > (q_pos[:, None] - window)
     if cfg.attention_chunk is not None:                    # llama4 chunked local
         m &= (k_pos[None, :] // cfg.attention_chunk) == (q_pos[:, None] // cfg.attention_chunk)
     return m
@@ -149,11 +225,13 @@ def _attn_layout(rules, nq, sq):
 
 
 def chunked_attention(q, k, v, q_positions, k_positions, cfg: ModelConfig,
-                      kv_chunk: int = 1024, logit_softcap=None, rules=None):
+                      kv_chunk: int = 1024, logit_softcap=None, rules=None,
+                      window=None):
     """Causal GQA attention with online softmax over KV chunks.
 
     q: (b, sq, nq, hd);  k, v: (b, sk, nkv, hd)
     q_positions: (b, sq);  k_positions: (b, sk)
+    window: the layer's own window (see ``_band_mask``), or None
     Returns (b, sq, nq, hd).
 
     Sharding: the (b, sq, nq, C) score tensor must shard over the tensor
@@ -207,7 +285,8 @@ def chunked_attention(q, k, v, q_positions, k_positions, cfg: ModelConfig,
     def body(carry, chunk):
         m_prev, l_prev, acc_prev = carry
         kj, vj, posj = chunk                        # (b, C, nkv, hd), (b, C)
-        mask = jax.vmap(lambda qp, kp: _band_mask(qp, kp, cfg))(q_positions, posj)
+        mask = jax.vmap(lambda qp, kp: _band_mask(qp, kp, cfg, window))(
+            q_positions, posj)
         if expand_kv:
             kj = cst(jnp.repeat(kj, gq, axis=2), P(bax, None, ax, None))
             vj = cst(jnp.repeat(vj, gq, axis=2), P(bax, None, ax, None))
@@ -269,13 +348,15 @@ def attention_qkv(x, p, cfg: ModelConfig, rules=None):
 
 
 def attention_layer(x, p, cfg: ModelConfig, positions, rules=None,
-                    positions_3d=None):
-    """Full-sequence attention sublayer (train / prefill). Returns (y, (k, v))."""
+                    positions_3d=None, kind: Optional[LayerKind] = None):
+    """Full-sequence attention sublayer (train / prefill). Returns (y, (k, v)).
+    ``kind``: the layer's row of :func:`layer_kinds` in a mixed stack."""
     q, k, v = attention_qkv(x, p, cfg, rules)
-    q = position_embedding(q, positions, cfg, positions_3d)
-    k = position_embedding(k, positions, cfg, positions_3d)
+    q = position_embedding(q, positions, cfg, positions_3d, kind)
+    k = position_embedding(k, positions, cfg, positions_3d, kind)
     o = chunked_attention(q, k, v, positions, positions, cfg,
-                          logit_softcap=cfg.logit_softcap, rules=rules)
+                          logit_softcap=cfg.logit_softcap, rules=rules,
+                          window=None if kind is None else kind.window)
     o = shard(o, rules, "act_batch", "act_seq", "act_heads", None)
     y = jnp.einsum("bsnh,nhd->bsd", o, p["wo"])
     # emitted KV (prefill cache material) shards its seq dim over "model" —

@@ -25,7 +25,7 @@ from repro.core import paged_attention as pa
 from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models import xlstm as X
-from repro.models.moe import moe_layer
+from repro.models.moe import moe_layer, moe_served
 from repro.models.sharding import ShardingRules, shard
 from repro.models.params import (  # noqa: F401  (re-exported)
     abstract_params, build_schema, init_params, param_count, param_specs)
@@ -63,31 +63,47 @@ def unembed(params, x, cfg: ModelConfig, rules=None):
 # ---------------------------------------------------------------------------
 
 
-def _attn_sublayer(x, lp, cfg, positions, rules, positions_3d):
+def _attn_sublayer(x, lp, cfg, positions, rules, positions_3d, kind=None):
     u = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
-    a, kv = L.attention_layer(u, lp["attn"], cfg, positions, rules, positions_3d)
+    a, kv = L.attention_layer(u, lp["attn"], cfg, positions, rules,
+                              positions_3d, kind)
     return u, a, kv
 
 
+def _moe_sublayer(u, lp, cfg: ModelConfig, rules, train: bool):
+    """The MoE sublayer: ``(y, aux, routes)``.  For the training loss it
+    is the capacity dispatch and its aux loss (routes None); every other
+    pass serves without drops over the held experts (``routes``: (held,)
+    int32 routes to each, aux 0)."""
+    if train:
+        y, aux = moe_layer(u, lp["moe"], cfg, rules)
+        return y, aux, None
+    y, routes = moe_served(u, lp["moe"], cfg, rules)
+    return y, jnp.zeros((), jnp.float32), routes
+
+
 def dense_block(x, lp, cfg: ModelConfig, positions, rules=None,
-                positions_3d=None, is_moe=False):
-    """Pre-LN block. Returns (x, kv, aux)."""
+                positions_3d=None, is_moe=False, kind=None, train=False):
+    """Pre-LN block. Returns (x, kv, aux, routes); ``routes`` is None but
+    for a served MoE block (see :func:`_moe_sublayer`)."""
     aux = jnp.zeros((), jnp.float32)
+    routes = None
     if cfg.parallel_block:
         u, a, kv = _attn_sublayer(x, lp, cfg, positions, rules, positions_3d)
         m = L.mlp(u, lp["mlp"], cfg, rules)
         x = x + a + m
-        return x, kv, aux
-    u, a, kv = _attn_sublayer(x, lp, cfg, positions, rules, positions_3d)
+        return x, kv, aux, routes
+    u, a, kv = _attn_sublayer(x, lp, cfg, positions, rules, positions_3d,
+                              kind)
     x = x + a
     u2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
     if is_moe:
-        y, aux = moe_layer(u2, lp["moe"], cfg, rules)
+        y, aux, routes = _moe_sublayer(u2, lp, cfg, rules, train)
     else:
         y = L.mlp(u2, lp["mlp"], cfg, rules)
     x = x + y
     x = shard(x, rules, "act_batch", "act_seq", "act_embed")
-    return x, kv, aux
+    return x, kv, aux, routes
 
 
 # ---------------------------------------------------------------------------
@@ -100,6 +116,7 @@ class ForwardOut(NamedTuple):
     kv: Optional[Any]            # stacked (L_kv, b, s, nkv, hd) k and v
     states: Optional[Any]        # SSM / xLSTM final states
     aux: jnp.ndarray             # scalar aux loss (MoE load balance)
+    routes: Optional[Any] = None  # served MoE: (L_moe, held) int32 routes
 
 
 def _scan(body, x, stacks, length=None):
@@ -107,37 +124,45 @@ def _scan(body, x, stacks, length=None):
 
 
 def backbone(params, x, positions, cfg: ModelConfig, rules=None,
-             positions_3d=None, want_kv: bool = True) -> ForwardOut:
+             positions_3d=None, want_kv: bool = True,
+             train: bool = False) -> ForwardOut:
+    """``want_kv`` returns the cache material; ``train`` runs MoE layers
+    through the training dispatch and its aux loss, and otherwise they
+    serve without drops and count their routes."""
     fam = cfg.family
 
     if fam in ("dense", "vlm", "audio") or (fam == "moe" and cfg.moe.layer_period == 1):
         is_moe = fam == "moe"
 
-        def body(h, lp):
-            h, kv, aux = dense_block(h, lp, cfg, positions, rules,
-                                     positions_3d, is_moe=is_moe)
-            return h, (kv if want_kv else None, aux)
+        def body(h, xs):
+            lp, kind = xs
+            h, kv, aux, routes = dense_block(
+                h, lp, cfg, positions, rules, positions_3d, is_moe=is_moe,
+                kind=kind, train=train)
+            return h, (kv if want_kv else None, aux, routes)
 
-        x, (kvs, auxs) = _scan(body, x, params["layers"])
-        return ForwardOut(x, kvs, None, auxs.sum())
+        x, (kvs, auxs, routes) = _scan(
+            body, x, (params["layers"], L.layer_kinds(cfg)))
+        return ForwardOut(x, kvs, None, auxs.sum(), routes)
 
     if fam == "moe":  # interleaved dense/moe pairs (llama4)
         def body(h, lps):
             dlp, mlp_ = lps
-            h, kv1, _ = dense_block(h, dlp, cfg, positions, rules,
-                                    positions_3d, is_moe=False)
-            h, kv2, aux = dense_block(h, mlp_, cfg, positions, rules,
-                                      positions_3d, is_moe=True)
+            h, kv1, _, _ = dense_block(h, dlp, cfg, positions, rules,
+                                       positions_3d, is_moe=False)
+            h, kv2, aux, routes = dense_block(h, mlp_, cfg, positions, rules,
+                                              positions_3d, is_moe=True,
+                                              train=train)
             kv = jax.tree.map(lambda a, b: jnp.stack([a, b]), kv1, kv2) \
                 if want_kv else None
-            return h, (kv, aux)
+            return h, (kv, aux, routes)
 
-        x, (kvs, auxs) = _scan(body, x, (params["blocks"]["dense"],
-                                         params["blocks"]["moe"]))
+        x, (kvs, auxs, routes) = _scan(body, x, (params["blocks"]["dense"],
+                                                 params["blocks"]["moe"]))
         # (n_pairs, 2, b, s, nkv, hd) -> (L, b, s, nkv, hd)
         if want_kv:
             kvs = jax.tree.map(lambda t: t.reshape((-1,) + t.shape[2:]), kvs)
-        return ForwardOut(x, kvs, None, auxs.sum())
+        return ForwardOut(x, kvs, None, auxs.sum(), routes)
 
     if fam == "hybrid":
         return _hybrid_backbone(params, x, positions, cfg, rules,
@@ -256,8 +281,10 @@ def _xlstm_backbone(params, x, cfg: ModelConfig, rules=None,
 # ---------------------------------------------------------------------------
 
 
-def forward(params, batch, cfg: ModelConfig, rules=None, want_kv: bool = False):
-    """batch: dict(tokens, positions[, prefix_embeddings, positions_3d])."""
+def forward(params, batch, cfg: ModelConfig, rules=None, want_kv: bool = False,
+            train: bool = False):
+    """batch: dict(tokens, positions[, prefix_embeddings, positions_3d]).
+    ``train``: the pass the training loss differentiates."""
     x = embed(params, batch["tokens"], cfg, rules)
     if cfg.modality is not None and cfg.modality.num_prefix_embeddings:
         # frontend stub: precomputed patch/frame/conditioning embeddings
@@ -267,13 +294,13 @@ def forward(params, batch, cfg: ModelConfig, rules=None, want_kv: bool = False):
     if cfg.rope_style == "none" and cfg.family == "audio":
         x = x + L.sinusoidal_positions(positions, cfg.d_model).astype(x.dtype)[..., :x.shape[-1]]
     out = backbone(params, x, positions, cfg, rules,
-                   batch.get("positions_3d"), want_kv=want_kv)
+                   batch.get("positions_3d"), want_kv=want_kv, train=train)
     logits = unembed(params, out.hidden, cfg, rules)
     return logits, out
 
 
 def loss_fn(params, batch, cfg: ModelConfig, rules=None):
-    logits, out = forward(params, batch, cfg, rules)
+    logits, out = forward(params, batch, cfg, rules, train=True)
     labels = batch["labels"]
     npre = (cfg.modality.num_prefix_embeddings if cfg.modality else 0)
     if npre:
@@ -324,6 +351,9 @@ class DecodeState(NamedTuple):
     peer: Optional[KVPools]  # harvested peer tier (in-place mode)
     states: Optional[Any]    # SSM / xLSTM recurrent states
     positions_3d: Optional[jnp.ndarray] = None
+    #: out of a served MoE step: (L_moe, held) int32 routes to each held
+    #: expert in each MoE layer; None in, and out of a model without
+    routes: Optional[jnp.ndarray] = None
 
 
 def num_kv_layers(cfg: ModelConfig) -> int:
@@ -335,7 +365,7 @@ def num_kv_layers(cfg: ModelConfig) -> int:
 
 
 def _decode_attention_carried(q, pools_full, layer, state, k_new, v_new,
-                              cfg, rules, peer_full=None):
+                              cfg, rules, peer_full=None, window=None):
     """One layer's paged attention against the CARRIED full pools.
 
     ``pools_full``: (L_kv, n_slots, bs, nkv, hd) k and v, loop-carried so the
@@ -367,7 +397,8 @@ def _decode_attention_carried(q, pools_full, layer, state, k_new, v_new,
             ppk = jax.lax.dynamic_index_in_dim(ppkf, lyr, 0, keepdims=False)
             ppv = jax.lax.dynamic_index_in_dim(ppvf, lyr, 0, keepdims=False)
             pools.append((ppk, ppv, psr, psb))
-        out = pa.paged_decode_attention(q, pools, state.pos, cfg, axis_names)
+        out = pa.paged_decode_attention(q, pools, state.pos, cfg, axis_names,
+                                        window)
         return out.astype(q.dtype), pkf, pvf
 
     pkf, pvf = pools_full
@@ -446,17 +477,18 @@ def _decode_attention(q, layer_pools, q_pos, cfg, rules, peer_layer_pools=None):
 
 
 def _decode_attn_sublayer_carried(x, lp, cfg, state: DecodeState, pools_full,
-                                  layer, rules, peer_full=None):
-    """x: (b, 1, d). Returns (attn_out (b,1,d), updated full pools)."""
+                                  layer, rules, peer_full=None, kind=None):
+    """x: (b, 1, d). Returns (attn_out (b,1,d), updated full pools).
+    ``kind``: the layer's row of ``L.layer_kinds`` in a mixed stack."""
     u = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
     q, k, v = L.attention_qkv(u, lp["attn"], cfg, rules)
     positions = state.pos[:, None]
     p3 = state.positions_3d[:, None] if state.positions_3d is not None else None
-    q = L.position_embedding(q, positions, cfg, p3)
-    k = L.position_embedding(k, positions, cfg, p3)
+    q = L.position_embedding(q, positions, cfg, p3, kind)
+    k = L.position_embedding(k, positions, cfg, p3, kind)
     o, new_pk, new_pv = _decode_attention_carried(
         q[:, 0], pools_full, layer, state, k[:, 0], v[:, 0], cfg, rules,
-        peer_full)
+        peer_full, None if kind is None else kind.window)
     y = jnp.einsum("bnh,nhd->bd", o.astype(x.dtype), lp["attn"]["wo"])
     return y[:, None], (new_pk, new_pv)
 
@@ -501,9 +533,18 @@ def serve_step(params, state: DecodeState, cfg: ModelConfig, rules=None,
 
     use_peer = harvest_inplace and state.peer is not None
     peer_kv_stack = (state.peer.pool_k, state.peer.pool_v) if use_peer else None
+    routes = None
 
     if fam in ("dense", "vlm", "audio", "moe"):
         interleaved = fam == "moe" and cfg.moe.layer_period == 2
+
+        def ffn(h, lp, is_moe):
+            """The block's second half on h (attention added): (h, routes)."""
+            u2 = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
+            if is_moe:
+                y, _, r = _moe_sublayer(u2, lp, cfg, rules, train=False)
+                return h + y, r
+            return h + L.mlp(u2, lp["mlp"], cfg, rules), None
 
         def one_layer(h, lp, layer_kv, peer_slice, is_moe):
             a, new_slice = _decode_attn_sublayer(h, lp, cfg, state, layer_kv,
@@ -512,64 +553,62 @@ def serve_step(params, state: DecodeState, cfg: ModelConfig, rules=None,
                 # parallel block: attn and mlp both read ln1(x)
                 u = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
                 h = h + a + L.mlp(u, lp["mlp"], cfg, rules)
-                return h, new_slice
-            h = h + a
-            u2 = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
-            if is_moe:
-                y, _ = moe_layer(u2, lp["moe"], cfg, rules)
-                h = h + y
-            else:
-                h = h + L.mlp(u2, lp["mlp"], cfg, rules)
-            return h, new_slice
+                return h, new_slice, None
+            h, r = ffn(h + a, lp, is_moe)
+            return h, new_slice, r
 
         if not interleaved and not carried_pools:
             # §Perf baseline variant: pools as scan xs/ys (full per-layer
             # slice rewrite each step) — kept for before/after measurement
+            if cfg.mixed_attention is not None:
+                raise NotImplementedError("mixed attention decodes with "
+                                          "carried pools only")
+
             def body(h, inp):
                 lp, pk, pv, peer = inp
-                h, new_slice = one_layer(h, lp, (pk, pv),
-                                         peer if use_peer else None,
-                                         fam == "moe")
-                return h, new_slice
+                h, new_slice, r = one_layer(h, lp, (pk, pv),
+                                            peer if use_peer else None,
+                                            fam == "moe")
+                return h, (new_slice, r)
 
             xs = (params["layers"], state.kv.pool_k, state.kv.pool_v,
                   peer_kv_stack if use_peer else state.kv.pool_k)
-            x, (pks, pvs) = jax.lax.scan(body, x, xs)
+            x, ((pks, pvs), routes) = jax.lax.scan(body, x, xs)
         elif not interleaved:
             # pools ride in the scan CARRY: the KV append is a 3-index
             # scatter into the full pool (writes only b rows/layer) instead
             # of a full per-layer slice rewrite through scan ys (§Perf it.3)
+            kinds = L.layer_kinds(cfg)
+
             def body(carry, lp):
                 h, pkf, pvf, lyr = carry
+                kind = (None if kinds is None else
+                        jax.tree.map(lambda t: t[lyr], kinds))
                 a, (pkf, pvf) = _decode_attn_sublayer_carried(
                     h, lp, cfg, state, (pkf, pvf), lyr, rules,
-                    peer_kv_stack if use_peer else None)
+                    peer_kv_stack if use_peer else None, kind)
+                r = None
                 if cfg.parallel_block:
                     u = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
                     h = h + a + L.mlp(u, lp["mlp"], cfg, rules)
                 else:
-                    h = h + a
-                    u2 = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
-                    if fam == "moe":
-                        y, _ = moe_layer(u2, lp["moe"], cfg, rules)
-                        h = h + y
-                    else:
-                        h = h + L.mlp(u2, lp["mlp"], cfg, rules)
-                return (h, pkf, pvf, lyr + 1), None
+                    h, r = ffn(h + a, lp, fam == "moe")
+                return (h, pkf, pvf, lyr + 1), r
 
-            (x, pks, pvs, _), _ = jax.lax.scan(
+            (x, pks, pvs, _), routes = jax.lax.scan(
                 body, (x, state.kv.pool_k, state.kv.pool_v,
                        jnp.zeros((), jnp.int32)), params["layers"])
         else:
             def body(h, inp):
                 dlp, mlp_, pk, pv, peer = inp
-                h, s1 = one_layer(h, dlp, (pk[0], pv[0]),
-                                  (peer[0][0], peer[1][0]) if use_peer else None,
-                                  False)
-                h, s2 = one_layer(h, mlp_, (pk[1], pv[1]),
-                                  (peer[0][1], peer[1][1]) if use_peer else None,
-                                  True)
-                return h, (jnp.stack([s1[0], s2[0]]), jnp.stack([s1[1], s2[1]]))
+                h, s1, _ = one_layer(
+                    h, dlp, (pk[0], pv[0]),
+                    (peer[0][0], peer[1][0]) if use_peer else None, False)
+                h, s2, r = one_layer(
+                    h, mlp_, (pk[1], pv[1]),
+                    (peer[0][1], peer[1][1]) if use_peer else None, True)
+                return h, (jnp.stack([s1[0], s2[0]]),
+                           jnp.stack([s1[1], s2[1]]), r)
 
             nk = num_kv_layers(cfg)
             pk2 = state.kv.pool_k.reshape((nk // 2, 2) + state.kv.pool_k.shape[1:])
@@ -580,7 +619,7 @@ def serve_step(params, state: DecodeState, cfg: ModelConfig, rules=None,
                 peer_xs = (ppk2, ppv2)
             else:
                 peer_xs = (pk2, pv2)
-            x, (pks, pvs) = jax.lax.scan(
+            x, (pks, pvs, routes) = jax.lax.scan(
                 body, x, (params["blocks"]["dense"], params["blocks"]["moe"],
                           pk2, pv2, peer_xs))
             pks = pks.reshape((-1,) + pks.shape[2:])
@@ -649,5 +688,6 @@ def serve_step(params, state: DecodeState, cfg: ModelConfig, rules=None,
     logits = unembed(params, x, cfg, rules)[:, 0]
     new_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     new_state = state._replace(
-        tokens=new_tokens, pos=state.pos + 1, kv=new_kv, states=new_states)
+        tokens=new_tokens, pos=state.pos + 1, kv=new_kv, states=new_states,
+        routes=routes)
     return logits, new_state

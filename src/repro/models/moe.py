@@ -1,16 +1,24 @@
-"""Mixture-of-Experts layer with capacity-based sort dispatch.
+"""Mixture-of-Experts layers: a capacity dispatch for training and a
+grouped, drop-free one for serving.
 
-Dispatch is the argsort/segment scheme (Megablocks-style dense capacity
-buffers, no (t, E, C) one-hot): tokens are sorted by expert id, ranked within
-their expert segment, and scattered into an (E, C, d) dispatch buffer.  The
-expert dimension shards over the ``model`` mesh axis (expert parallelism);
-expert weight d_model dims shard over ``data`` (FSDP).  GSPMD inserts the
-all-to-all / all-gather collectives.
+Training (:func:`moe_layer`) uses the argsort/segment scheme (Megablocks-style
+dense capacity buffers, no (t, E, C) one-hot): tokens are sorted by expert
+id, ranked within their expert segment, and scattered into an (E, C, d)
+dispatch buffer; routes over capacity are dropped.  The expert dimension
+shards over the ``model`` mesh axis (expert parallelism); expert weight
+d_model dims shard over ``data`` (FSDP).  GSPMD inserts the all-to-all /
+all-gather collectives.
 
-The Harvest Expert Rebalancer (repro/core/rebalancer.py) manages *which* copy
-of each expert's weights is fed here (local HBM / harvested peer HBM / host
-DRAM) — the math below is placement-agnostic, which is exactly the paper's
-"no model code changes" property.
+Serving (:func:`moe_served`) never drops a route.  The layer holds experts
+``first_held .. first_held + num_held - 1`` of the router's ``num_experts``
+(``MoEConfig``); the router scores all of them, and the layer adds
+gate x expert(x) for each route to a held expert, through grouped matmuls
+over the routes sorted by expert.  A route to an expert held elsewhere
+adds nothing here: that part of the output is another chip's.
+
+The expert weights are the layer's arguments, wherever they came from; the
+Harvest Expert Rebalancer (repro/core/rebalancer.py) plans expert placement
+on its own simulated clock and does not feed this layer.
 """
 from __future__ import annotations
 
@@ -87,10 +95,116 @@ def expert_ffn(xd, p, cfg: ModelConfig, rules=None):
     return jnp.einsum("ecf,efd->ecd", h, p["wo"])
 
 
+def _held_ids(ids, first, held: int, experts: int):
+    """Top-k ids as indices into the held experts ``first .. first + held
+    - 1``; a route to an expert held elsewhere reads ``held``, past every
+    held one."""
+    if held == experts:
+        return ids
+    local = ids - first
+    return jnp.where((local >= 0) & (local < held), local, held)
+
+
+def _served_share(xt, p, cfg: ModelConfig, first, held: int):
+    """The routes of tokens ``xt`` (t, d) to experts ``first .. first +
+    held - 1``, whose weights ``p`` holds: (y (t, d) float32, routes
+    (held,) int32)."""
+    mc = cfg.moe
+    t, d = xt.shape
+    k = mc.top_k
+    logits = jnp.einsum("td,de->te", xt, p["router"],
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)
+    weights, ids, _ = router_topk(logits, k)
+    local = _held_ids(ids, first, held, mc.num_experts).reshape(-1)
+    routes = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
+    rows = t * min(k, held)
+    order = jnp.argsort(local, stable=True)[:rows]
+    tok = order // k
+    live = jnp.arange(rows) < routes.sum()
+    xs = jnp.take(xt, tok, axis=0)
+    act = _activation(cfg.activation)
+    h = act(jax.lax.ragged_dot(xs, p["wg"], routes)) * jax.lax.ragged_dot(
+        xs, p["wi"], routes)
+    out = jax.lax.ragged_dot(h, p["wo"], routes)
+    # rows past the held routes are in no group: what a backend leaves
+    # there is undefined (not zero on a TPU), so they are selected away,
+    # not multiplied by a zero gate
+    y = jnp.zeros((t, d), jnp.float32).at[tok].add(jnp.where(
+        live[:, None],
+        out.astype(jnp.float32) * weights.reshape(-1)[order][:, None], 0.0))
+    return y, routes
+
+
+def moe_served(x, p, cfg: ModelConfig, rules=None):
+    """The served MoE sublayer: no route dropped. x: (b, s, d) ->
+    (y, routes), ``routes`` (num_held,) int32: the routes to each held
+    expert.
+
+    Routes are sorted by held expert, those to absent experts last, and
+    the first ``t * min(top_k, num_held)`` of them, a bound no token's
+    routes can pass, run through three grouped matmuls
+    (``jax.lax.ragged_dot``), so the experts' work grows with the routes
+    to them, not with tokens x experts.  Rows past the held routes add
+    nothing, whatever the grouped matmul leaves in them.  Gates combine
+    in float32.
+
+    On a mesh each shard of the expert axis holds its contiguous share of
+    the experts, serves the routes to them, and the shares' outputs are
+    summed over that axis: expert parallelism with nothing dropped.
+    """
+    mc = cfg.moe
+    b, s, d = x.shape
+    eax = rules.axis("experts") if rules is not None else None
+    if eax is None:
+        y, routes = _served_share(x.reshape(b * s, d), p, cfg,
+                                  mc.first_held, mc.num_held)
+        y = y.astype(x.dtype).reshape(b, s, d)
+    else:
+        y, routes = _served_sharded(x, p, cfg, rules, eax)
+    if mc.num_shared_experts:
+        y = y + mlp(x, p["shared"], cfg, rules)
+    return y, routes
+
+
+def _served_sharded(x, p, cfg: ModelConfig, rules, eax):
+    """:func:`moe_served` over the mesh's expert axis ``eax`` (see there).
+    Every expert must be held and the axis must split them evenly."""
+    mc = cfg.moe
+    esize = rules.axis_size(eax)
+    if mc.num_held != mc.num_experts or mc.num_experts % esize:
+        raise ValueError(
+            f"a mesh serves all {mc.num_experts} experts split evenly over "
+            f"its expert axis ({esize} shards); this layer holds "
+            f"{mc.num_held}")
+    held = mc.num_experts // esize
+    dax = rules.axis("act_batch")
+    if dax is not None and x.shape[0] % rules.axis_size(dax):
+        dax = None
+    d = x.shape[-1]
+
+    def share(xl, router, wi, wg, wo):
+        first = jax.lax.axis_index(eax) * held
+        y, routes = _served_share(
+            xl.reshape(-1, d), dict(router=router, wi=wi, wg=wg, wo=wo),
+            cfg, first, held)
+        y = jax.lax.psum(y, eax).astype(xl.dtype).reshape(xl.shape)
+        if dax is not None:
+            routes = jax.lax.psum(routes, dax)
+        return y, routes
+
+    y, routes = jax.shard_map(
+        share, mesh=rules.mesh,
+        in_specs=(P(dax), P(), P(eax), P(eax), P(eax)),
+        out_specs=(P(dax), P(eax)), check_vma=False,
+    )(x, p["router"], p["wi"], p["wg"], p["wo"])
+    return shard(y, rules, "act_batch", "act_seq", "act_embed"), routes
+
+
 def moe_layer(x, p, cfg: ModelConfig, rules=None,
               capacity_factor: Optional[float] = None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """MoE FFN sublayer. x: (b, s, d) -> (y, aux_loss).
+    """The training MoE FFN sublayer. x: (b, s, d) -> (y, aux_loss).
 
     With a batch-sharded mesh the dispatch/combine runs LOCALLY per data
     shard (shard_map over the batch axis, expert axis left to GSPMD):
@@ -109,6 +223,7 @@ def moe_layer(x, p, cfg: ModelConfig, rules=None,
     eax = rules.axis("experts") if rules is not None else None
     if (ax is not None and eax is not None
             and x.shape[0] % rules.axis_size(ax) == 0
+            and mc.num_held == mc.num_experts
             and mc.num_experts % rules.axis_size(eax) == 0):
         return _moe_layer_local(x, p, cfg, rules, capacity_factor)
     b, s, d = x.shape
@@ -122,19 +237,19 @@ def moe_layer(x, p, cfg: ModelConfig, rules=None,
     aux = load_balance_loss(probs, ids, mc.num_experts)
 
     capacity = max(int(t * mc.top_k / mc.num_experts * capacity_factor), 4)
-    slot_token, slot_weight = build_dispatch(ids, weights, mc.num_experts, capacity)
+    slot_token, slot_weight = build_dispatch(
+        _held_ids(ids, mc.first_held, mc.num_held, mc.num_experts), weights,
+        mc.num_held, capacity)
 
     # gather tokens into (E, C, d); empty slots read token index t -> fill 0.
-    # (This path serves CPU/smoke runs, indivisible meshes and the
-    # batch-replicated decode shardings; batch-sharded training uses
-    # _moe_layer_local.  An "expert_capacity"@data constraint here was
-    # measured to REGRESS decode — §Perf iteration 4 — and is superseded.)
+    # (This path trains on one device and on indivisible meshes;
+    # batch-sharded training uses _moe_layer_local.)
     xd = jnp.take(xt, slot_token, axis=0, mode="fill", fill_value=0)
-    xd = xd.reshape(mc.num_experts, capacity, d)
+    xd = xd.reshape(mc.num_held, capacity, d)
     xd = shard(xd, rules, "experts", None, None)
 
     out = expert_ffn(xd, p, cfg, rules)            # (E, C, d)
-    out = out.reshape(mc.num_experts * capacity, d)
+    out = out.reshape(mc.num_held * capacity, d)
 
     y = jnp.zeros((t + 1, d), x.dtype)             # row t = drop bucket
     y = y.at[slot_token].add(out * slot_weight[:, None].astype(x.dtype))
@@ -146,28 +261,6 @@ def moe_layer(x, p, cfg: ModelConfig, rules=None,
     y = y.reshape(b, s, d)
     y = shard(y, rules, "act_batch", "act_seq", "act_embed")
     return y, aux
-
-
-def _moe_core(xt, p, cfg: ModelConfig, rules, capacity_factor: float):
-    """Router + dispatch + expert FFN + combine over a flat token batch."""
-    mc = cfg.moe
-    t, d = xt.shape
-    logits = jnp.einsum("td,de->te", xt, p["router"]).astype(jnp.float32)
-    weights, ids, probs = router_topk(logits, mc.top_k)
-    aux = load_balance_loss(probs, ids, mc.num_experts)
-
-    capacity = max(int(t * mc.top_k / mc.num_experts * capacity_factor), 4)
-    slot_token, slot_weight = build_dispatch(ids, weights, mc.num_experts,
-                                             capacity)
-    xd = jnp.take(xt, slot_token, axis=0, mode="fill", fill_value=0)
-    xd = xd.reshape(mc.num_experts, capacity, d)
-    xd = shard(xd, rules, "experts", None, None)
-
-    out = expert_ffn(xd, p, cfg, rules)            # (E, C, d)
-    out = out.reshape(mc.num_experts * capacity, d)
-    y = jnp.zeros((t + 1, d), xt.dtype)            # row t = drop bucket
-    y = y.at[slot_token].add(out * slot_weight[:, None].astype(xt.dtype))
-    return y[:t], aux
 
 
 def _moe_layer_local(x, p, cfg: ModelConfig, rules,

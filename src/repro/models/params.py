@@ -86,14 +86,12 @@ def mlp_schema(cfg: ModelConfig, d_ff: Optional[int] = None) -> dict:
 def moe_schema(cfg: ModelConfig) -> dict:
     mc = cfg.moe
     d = cfg.d_model
+    e = mc.num_held
     s = {
         "router": ParamDef((d, mc.num_experts), ("embed_fsdp", None), dtype="float32"),
-        "wi": ParamDef((mc.num_experts, d, mc.d_ff_expert),
-                       ("experts", "embed_fsdp", None)),
-        "wg": ParamDef((mc.num_experts, d, mc.d_ff_expert),
-                       ("experts", "embed_fsdp", None)),
-        "wo": ParamDef((mc.num_experts, mc.d_ff_expert, d),
-                       ("experts", None, "embed_fsdp")),
+        "wi": ParamDef((e, d, mc.d_ff_expert), ("experts", "embed_fsdp", None)),
+        "wg": ParamDef((e, d, mc.d_ff_expert), ("experts", "embed_fsdp", None)),
+        "wo": ParamDef((e, mc.d_ff_expert, d), ("experts", None, "embed_fsdp")),
     }
     if mc.num_shared_experts:
         s["shared"] = mlp_schema(cfg, mc.d_ff_shared * mc.num_shared_experts)

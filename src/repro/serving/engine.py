@@ -307,6 +307,9 @@ class EngineStats:
     #: unified MetricsRegistry snapshot (transfer queues, kv, prefetch, …),
     #: populated by ``HarvestServingEngine.run``
     metrics: Dict[str, dict] = field(default_factory=dict)
+    #: served MoE: routes to held experts over every MoE layer, by program
+    #: ("prefill", "decode"); empty for a model without experts
+    expert_routes: Dict[str, int] = field(default_factory=dict)
 
     def throughput(self) -> float:
         """Simulated tokens/s; 0.0 for zero-token or zero-clock runs (an
@@ -674,6 +677,8 @@ class HarvestServingEngine:
         self.stats = EngineStats()
         self._next_id = 0
         self._decode_fn = functools.partial(_serve_step, cfg=cfg, rules=None)
+        #: the last decode step's route counts (an MoE model's), on device
+        self._step_routes = None
         self._prefill_fn = functools.partial(_forward, cfg=cfg, rules=None,
                                              want_kv=True)
 
@@ -1097,7 +1102,8 @@ class HarvestServingEngine:
             self._set_state_row(row, out.states)
 
         with tracing.span("device.wait"):
-            last = np.asarray(logits[0, npre + n - 1])
+            last = self._read_back(logits[0, npre + n - 1], out.routes,
+                                   "prefill")
         nxt = self._sample(last)
         if not r.output:
             r.output.append(int(nxt))
@@ -1185,7 +1191,8 @@ class HarvestServingEngine:
                                   "chunk", allocated=True)
         if out.states is not None:
             self._set_state_row(row, out.states)
-        nxt = self._sample(np.asarray(logits[0, npre + n - 1]))
+        nxt = self._sample(self._read_back(
+            logits[0, npre + n - 1], out.routes, "prefill"))
         if not r.output:
             # a rollback re-prefill replays the prefix without re-emitting
             r.output.append(int(nxt))
@@ -1301,7 +1308,8 @@ class HarvestServingEngine:
                     self.stats.writeback_s += tr.seconds
                     stream.append(tr)
 
-        nxt = self._sample(np.asarray(logits[0, npre + n - 1]))
+        nxt = self._sample(self._read_back(
+            logits[0, npre + n - 1], out.routes, "prefill"))
         if not r.output:
             r.output.append(int(nxt))
             self.stats.tokens_out += 1
@@ -1744,6 +1752,7 @@ class HarvestServingEngine:
             positions_3d=(jnp.stack([jnp.asarray(self.row_pos)] * 3, -1)
                           if self.cfg.rope_style == "mrope" else None))
         logits, new_state = self._decode_fn(self.params, state)
+        self._step_routes = new_state.routes
         if self.L_kv:
             self.pool_k = new_state.kv.pool_k
             self.pool_v = new_state.kv.pool_v
@@ -1800,13 +1809,33 @@ class HarvestServingEngine:
             qb["q.batch.q_occupancy"] = (qb["q.batch.q_row_s"]
                                          / qb["q.batch.q_cap_s"])
 
+    def _read_back(self, logits, routes, program: str) -> np.ndarray:
+        """``logits`` to the host; an MoE program's ``routes``, its
+        (layers, held) int32 routes to each held expert, come in the same
+        transfer and are counted in ``stats.expert_routes`` and recorded
+        as a ``moe.routes`` event (per-layer ``routes_held`` and
+        ``max_routes``, the (layer, expert) pairs ``experts_touched``)."""
+        if routes is None:
+            return np.asarray(logits)
+        logits, routes = jax.device_get((logits, routes))
+        er = self.stats.expert_routes
+        er[program] = er.get(program, 0) + int(routes.sum())
+        if tracing.on():
+            tracing.event("moe.routes", program=program,
+                          routes_held=routes.sum(1).tolist(),
+                          experts_touched=int((routes > 0).sum()),
+                          max_routes=routes.max(1).tolist(),
+                          layers=routes.shape[0], held=routes.shape[1])
+        return np.asarray(logits)
+
     def _commit_and_sample(self, logits, reqs: Sequence[Request]) -> None:
         """Sample one token per decoding request, commit it, and stream it
         to the request's callback (the clock has already advanced past
         this step's window, so the timestamp is the token's ready time)."""
         with tracing.span("engine.sample", rows=len(reqs)):
             with tracing.span("device.wait"):
-                logits_np = np.asarray(logits)
+                logits_np = self._read_back(logits, self._step_routes,
+                                            "decode")
             now = self._now()
             for r in reqs:
                 tok = self._sample(logits_np[r.row])
