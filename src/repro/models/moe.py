@@ -1,5 +1,5 @@
 """Mixture-of-Experts layers: a capacity dispatch for training and a
-grouped, drop-free one for serving.
+drop-free one for serving.
 
 Training (:func:`moe_layer`) uses the argsort/segment scheme (Megablocks-style
 dense capacity buffers, no (t, E, C) one-hot): tokens are sorted by expert
@@ -12,8 +12,10 @@ all-gather collectives.
 Serving (:func:`moe_served`) never drops a route.  The layer holds experts
 ``first_held .. first_held + num_held - 1`` of the router's ``num_experts``
 (``MoEConfig``); the router scores all of them, and the layer adds
-gate x expert(x) for each route to a held expert, through grouped matmuls
-over the routes sorted by expert.  A route to an expert held elsewhere
+gate x expert(x) for each route to a held expert: through grouped matmuls
+over the routes sorted by expert, or, where the routes touch most held
+experts and the tokens are few enough, as one dense product of every
+token through every held expert.  A route to an expert held elsewhere
 adds nothing here: that part of the output is another chip's.
 
 The expert weights are the layer's arguments, wherever they came from; the
@@ -105,19 +107,54 @@ def _held_ids(ids, first, held: int, experts: int):
     return jnp.where((local >= 0) & (local < held), local, held)
 
 
-def _served_share(xt, p, cfg: ModelConfig, first, held: int):
-    """The routes of tokens ``xt`` (t, d) to experts ``first .. first +
-    held - 1``, whose weights ``p`` holds: (y (t, d) float32, routes
-    (held,) int32)."""
-    mc = cfg.moe
-    t, d = xt.shape
-    k = mc.top_k
-    logits = jnp.einsum("td,de->te", xt, p["router"],
+#: A served share computes its held experts as one dense product over all
+#: tokens (:func:`_dense_experts`) when the router's experts expect at
+#: least this many of the tokens' routes each, and the product spans at
+#: most ``DENSE_MAX_PAIRS`` (token, held expert) pairs.  Both from timing
+#: the two paths on a TPU v5e at Mellum2's widths, 16 of 64 experts held,
+#: top 1 to 8, 32 to 4,096 tokens (``benchmarks/moe_path_probe.py``):
+#: the grouped matmuls read the experts the routes touch at about a fifth
+#: of HBM bandwidth, the dense product all held experts at ~80%, so at half
+#: a route an expert (top 1 over 32 tokens, 38% of the held experts
+#: touched) dense took 0.30 ms a layer and grouped 0.51.  Past its read,
+#: the dense product's work grows with tokens x held experts: at 16 held
+#: it led at every top-k up to 1,024 tokens (1.42 ms against 1.76-2.97)
+#: and trailed at 2,048 for top 1 and 2 (2.67 against 2.00-2.28).  At top
+#: 8 it still led at 4,096 (5.14 against 8.74), but its (held, t, ...)
+#: intermediates grow with every token, so the bound stays.
+DENSE_MIN_ROUTES_PER_EXPERT = 0.5
+DENSE_MAX_PAIRS = 16 * 1024
+
+
+def dense_experts(tokens: int, top_k: int, num_experts: int,
+                  held: int) -> bool:
+    """Whether a served share over ``tokens`` tokens computes its
+    ``held`` experts as one dense product (see ``DENSE_MAX_PAIRS``)."""
+    return (tokens * top_k >= DENSE_MIN_ROUTES_PER_EXPERT * num_experts
+            and tokens * held <= DENSE_MAX_PAIRS)
+
+
+def _route(xt, router, cfg: ModelConfig, first, held: int):
+    """Top-k routes of tokens ``xt`` (t, d): (local (t, k) indices into
+    the held experts ``first .. first + held - 1``, ``held`` for a route to
+    an expert held elsewhere; weights (t, k) float32; routes (held,) int32
+    to each held expert)."""
+    logits = jnp.einsum("td,de->te", xt, router,
                         precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=jnp.float32)
-    weights, ids, _ = router_topk(logits, k)
-    local = _held_ids(ids, first, held, mc.num_experts).reshape(-1)
-    routes = jnp.zeros((held + 1,), jnp.int32).at[local].add(1)[:held]
+    weights, ids, _ = router_topk(logits, cfg.moe.top_k)
+    local = _held_ids(ids, first, held, cfg.moe.num_experts)
+    routes = jnp.zeros((held + 1,), jnp.int32).at[local.reshape(-1)].add(1)
+    return local, weights, routes[:held]
+
+
+def _grouped_experts(xt, p, cfg: ModelConfig, local, weights, routes):
+    """The routes to held experts, sorted by expert, through three grouped
+    matmuls (``jax.lax.ragged_dot``): (t, d) float32."""
+    t, d = xt.shape
+    k = local.shape[1]
+    held = routes.shape[0]
+    local = local.reshape(-1)
     rows = t * min(k, held)
     order = jnp.argsort(local, stable=True)[:rows]
     tok = order // k
@@ -130,10 +167,42 @@ def _served_share(xt, p, cfg: ModelConfig, first, held: int):
     # rows past the held routes are in no group: what a backend leaves
     # there is undefined (not zero on a TPU), so they are selected away,
     # not multiplied by a zero gate
-    y = jnp.zeros((t, d), jnp.float32).at[tok].add(jnp.where(
+    return jnp.zeros((t, d), jnp.float32).at[tok].add(jnp.where(
         live[:, None],
         out.astype(jnp.float32) * weights.reshape(-1)[order][:, None], 0.0))
-    return y, routes
+
+
+def _dense_experts(xt, p, cfg: ModelConfig, local, weights, routes):
+    """Every token through every held expert, as batched products with
+    the expert leading both operands, then each token's routed pairs
+    combined: (t, d) float32."""
+    t, d = xt.shape
+    held = routes.shape[0]
+    mine = local[..., None] == jnp.arange(held)                # (t, k, held)
+    gate = jnp.sum(jnp.where(mine, weights[..., None], 0.0), axis=1).T
+    routed = mine.any(1).T                                     # (held, t)
+    xe = jnp.broadcast_to(xt, (held, t, d))
+    act = _activation(cfg.activation)
+    h = act(jnp.einsum("etd,edf->etf", xe, p["wg"])) * jnp.einsum(
+        "etd,edf->etf", xe, p["wi"])
+    out = jnp.einsum("etf,efd->etd", h, p["wo"])
+    # a pair without a route is selected away, not multiplied by a zero
+    # gate, so whatever its product holds adds nothing
+    return jnp.sum(jnp.where(routed[..., None],
+                             gate[..., None] * out.astype(jnp.float32), 0.0),
+                   axis=0)
+
+
+def _served_share(xt, p, cfg: ModelConfig, first, held: int):
+    """The routes of tokens ``xt`` (t, d) to experts ``first .. first +
+    held - 1``, whose weights ``p`` holds: (y (t, d) float32, routes
+    (held,) int32)."""
+    mc = cfg.moe
+    local, weights, routes = _route(xt, p["router"], cfg, first, held)
+    experts = (_dense_experts
+               if dense_experts(xt.shape[0], mc.top_k, mc.num_experts, held)
+               else _grouped_experts)
+    return experts(xt, p, cfg, local, weights, routes), routes
 
 
 def moe_served(x, p, cfg: ModelConfig, rules=None):
@@ -141,13 +210,16 @@ def moe_served(x, p, cfg: ModelConfig, rules=None):
     (y, routes), ``routes`` (num_held,) int32: the routes to each held
     expert.
 
-    Routes are sorted by held expert, those to absent experts last, and
-    the first ``t * min(top_k, num_held)`` of them, a bound no token's
-    routes can pass, run through three grouped matmuls
+    The path is chosen from the shapes alone (:func:`dense_experts`).
+    Grouped: routes are sorted by held expert, those to absent experts
+    last, and the first ``t * min(top_k, num_held)`` of them, a bound no
+    token's routes can pass, run through three grouped matmuls
     (``jax.lax.ragged_dot``), so the experts' work grows with the routes
     to them, not with tokens x experts.  Rows past the held routes add
-    nothing, whatever the grouped matmul leaves in them.  Gates combine
-    in float32.
+    nothing, whatever the grouped matmul leaves in them.  Dense: every
+    token through every held expert, each (token, expert) pair without a
+    route selected away.  Either way gates combine in float32 over the
+    same bf16 products.
 
     On a mesh each shard of the expert axis holds its contiguous share of
     the experts, serves the routes to them, and the shares' outputs are

@@ -101,6 +101,7 @@ from repro.core.store import Residency, Transfer
 from repro.core.tiers import H100_NVLINK, Fidelity, HardwareModel, Tier
 from repro.kernels.harvest_copy.ops import dequantize_blocks, quantize_blocks
 from repro.models import model as M
+from repro.models.moe import dense_experts
 from repro.serving.admission import (ADMISSION, AdmissionPolicy,
                                      AdmissionView, StabilityAdmission)
 from repro.serving.control import ControllerConfig, StabilityController
@@ -310,6 +311,9 @@ class EngineStats:
     #: served MoE: routes to held experts over every MoE layer, by program
     #: ("prefill", "decode"); empty for a model without experts
     expert_routes: Dict[str, int] = field(default_factory=dict)
+    #: served MoE: calls of each program whose expert layers computed the
+    #: held experts as one dense product (``models.moe.dense_experts``)
+    expert_dense_calls: Dict[str, int] = field(default_factory=dict)
 
     def throughput(self) -> float:
         """Simulated tokens/s; 0.0 for zero-token or zero-clock runs (an
@@ -1103,7 +1107,7 @@ class HarvestServingEngine:
 
         with tracing.span("device.wait"):
             last = self._read_back(logits[0, npre + n - 1], out.routes,
-                                   "prefill")
+                                   "prefill", logits.shape[1])
         nxt = self._sample(last)
         if not r.output:
             r.output.append(int(nxt))
@@ -1192,7 +1196,7 @@ class HarvestServingEngine:
         if out.states is not None:
             self._set_state_row(row, out.states)
         nxt = self._sample(self._read_back(
-            logits[0, npre + n - 1], out.routes, "prefill"))
+            logits[0, npre + n - 1], out.routes, "prefill", logits.shape[1]))
         if not r.output:
             # a rollback re-prefill replays the prefix without re-emitting
             r.output.append(int(nxt))
@@ -1309,7 +1313,7 @@ class HarvestServingEngine:
                     stream.append(tr)
 
         nxt = self._sample(self._read_back(
-            logits[0, npre + n - 1], out.routes, "prefill"))
+            logits[0, npre + n - 1], out.routes, "prefill", logits.shape[1]))
         if not r.output:
             r.output.append(int(nxt))
             self.stats.tokens_out += 1
@@ -1809,23 +1813,32 @@ class HarvestServingEngine:
             qb["q.batch.q_occupancy"] = (qb["q.batch.q_row_s"]
                                          / qb["q.batch.q_cap_s"])
 
-    def _read_back(self, logits, routes, program: str) -> np.ndarray:
+    def _read_back(self, logits, routes, program: str,
+                   tokens: int) -> np.ndarray:
         """``logits`` to the host; an MoE program's ``routes``, its
         (layers, held) int32 routes to each held expert, come in the same
         transfer and are counted in ``stats.expert_routes`` and recorded
         as a ``moe.routes`` event (per-layer ``routes_held`` and
-        ``max_routes``, the (layer, expert) pairs ``experts_touched``)."""
+        ``max_routes``, the (layer, expert) pairs ``experts_touched``, and
+        the ``path`` its expert layers took over the program's ``tokens``,
+        counted in ``stats.expert_dense_calls`` where dense)."""
         if routes is None:
             return np.asarray(logits)
         logits, routes = jax.device_get((logits, routes))
         er = self.stats.expert_routes
         er[program] = er.get(program, 0) + int(routes.sum())
+        mc = self.cfg.moe
+        dense = dense_experts(tokens, mc.top_k, mc.num_experts,
+                              routes.shape[1])
+        dc = self.stats.expert_dense_calls
+        dc[program] = dc.get(program, 0) + dense
         if tracing.on():
             tracing.event("moe.routes", program=program,
                           routes_held=routes.sum(1).tolist(),
                           experts_touched=int((routes > 0).sum()),
                           max_routes=routes.max(1).tolist(),
-                          layers=routes.shape[0], held=routes.shape[1])
+                          layers=routes.shape[0], held=routes.shape[1],
+                          path="dense" if dense else "grouped")
         return np.asarray(logits)
 
     def _commit_and_sample(self, logits, reqs: Sequence[Request]) -> None:
@@ -1835,7 +1848,7 @@ class HarvestServingEngine:
         with tracing.span("engine.sample", rows=len(reqs)):
             with tracing.span("device.wait"):
                 logits_np = self._read_back(logits, self._step_routes,
-                                            "decode")
+                                            "decode", self.B)
             now = self._now()
             for r in reqs:
                 tok = self._sample(logits_np[r.row])

@@ -18,6 +18,7 @@ from repro.configs import get_config
 from repro.core import HarvestRuntime, tracing
 from repro.models import layers as L
 from repro.models import model as M
+from repro.models import moe
 from repro.models.moe import moe_layer, moe_served
 from repro.serving import engine as E
 
@@ -103,6 +104,21 @@ def _serve(cfg, params, prompts, new_tokens):
     return eng, reqs, seen
 
 
+#: the two ways a served share computes its held experts
+PATHS = {"grouped": moe._grouped_experts, "dense": moe._dense_experts}
+
+
+def _through(path, x, lp, cfg):
+    """The served layer over ``x`` (b, s, d) with its held experts
+    computed by ``path``, whichever the shapes would choose: (y, routes)."""
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    local, weights, routes = moe._route(xt, lp["router"], cfg,
+                                        cfg.moe.first_held, cfg.moe.num_held)
+    y = PATHS[path](xt, lp, cfg, local, weights, routes)
+    return y.reshape(b, s, d), routes
+
+
 PROMPTS = [[3 + (7 * j + 5 * i) % 500 for j in range(n)]
            for i, n in enumerate((37, 29, 44))]
 
@@ -164,6 +180,11 @@ def test_the_engine_records_the_routes_it_reads(served):
         # every row the step ran routes top_k times in each layer, at
         # most both routes to held experts
         assert sum(e.args["routes_held"]) <= 4 * 2 * eng.B
+    # a decode step's 2 rows route 4 times among 8 experts, half a route
+    # each, and prefills more: every program here takes the dense path
+    assert {e.args["path"] for e in events} == {"dense"}
+    assert eng.stats.expert_dense_calls == {"prefill": len(reqs),
+                                            "decode": eng.commits}
 
 
 def test_a_dense_model_returns_and_records_no_routes():
@@ -179,9 +200,11 @@ def test_a_dense_model_returns_and_records_no_routes():
     eng.submit(list(range(3, 12)), 3)
     eng.run(max_steps=50)
     assert eng.stats.expert_routes == {}
+    assert eng.stats.expert_dense_calls == {}
 
 
-def test_the_shares_add_up_to_the_whole_layer():
+@pytest.mark.parametrize("path", PATHS)
+def test_the_shares_add_up_to_the_whole_layer(path):
     """Four chips holding two experts each: the served layer's four
     partial outputs, summed, are the uncut reference layer's output."""
     whole = _cfg(held=8)
@@ -193,14 +216,15 @@ def test_the_shares_add_up_to_the_whole_layer():
         cfg = _cfg(held=2, first=2 * chip)
         part = dict(lp, **{k: lp[k][2 * chip:2 * chip + 2]
                            for k in ("wi", "wg", "wo")})
-        y, routes = moe_served(x, part, cfg)
+        y, routes = _through(path, x, part, cfg)
         assert routes.shape == (2,)
         total = total + y
     want = ref._experts(x, lp, _conf(held=8), "f32")
     np.testing.assert_allclose(total, want, rtol=1e-5, atol=1e-5)
 
 
-def test_routing_forced_onto_one_expert_drops_nothing():
+@pytest.mark.parametrize("path", PATHS)
+def test_routing_forced_onto_one_expert_drops_nothing(path):
     """A router that scores every expert alike sends every token to
     experts 0 and 1 (ties go to the lower index): expert 0 takes all 96
     routes of its layer, 16 times what a capacity of 1.25 x the mean
@@ -211,33 +235,110 @@ def test_routing_forced_onto_one_expert_drops_nothing():
     lp = dict(lp, router=jnp.zeros_like(lp["router"]))
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 48, 256), F32)
     want = ref._experts(x, lp, _conf(), "f32")
-    y, routes = moe_served(x, lp, cfg)
+    y, routes = _through(path, x, lp, cfg)
     assert routes.tolist() == [96, 96]
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
     dropped, _ = moe_layer(x, lp, cfg)
     assert np.abs(np.asarray(dropped) - np.asarray(want)).max() > 1e-2
 
 
-def test_rows_past_the_held_routes_add_nothing(monkeypatch):
+@pytest.mark.parametrize("path", PATHS)
+def test_rows_past_the_held_routes_add_nothing(path, monkeypatch):
     """``jax.lax.ragged_dot`` leaves rows that fall in no group undefined:
     on a TPU its kernel visits only the tiles that hold a group's rows and
     writes only those rows.  A grouped matmul that fills the others with
-    NaN leaves the served layer's output as the reference's."""
-    real = jax.lax.ragged_dot
-
-    def undefined_past_groups(lhs, rhs, group_sizes, **kw):
-        out = real(lhs, rhs, group_sizes, **kw)
-        past = jnp.arange(out.shape[0]) >= group_sizes.sum()
-        return jnp.where(past[:, None], jnp.nan, out)
-
-    monkeypatch.setattr(jax.lax, "ragged_dot", undefined_past_groups)
+    NaN leaves the grouped path's output as the reference's.  The dense
+    path's rows are its (expert, token) pairs: filled with NaN where the
+    token has no route to the expert, they leave its output so too."""
     cfg = _cfg()
     lp = jax.tree.map(lambda a: a[2], _params(cfg, seed=7)["layers"]["moe"])
     x = jax.random.normal(jax.random.PRNGKey(8), (2, 24, 256), F32)
-    y, routes = moe_served(x, lp, cfg)
+    local, _, _ = moe._route(x.reshape(-1, 256), lp["router"], cfg, 0, HELD)
+    unrouted = ~(local[..., None] == jnp.arange(HELD)).any(1).T
+    real_ragged, real_einsum = jax.lax.ragged_dot, jnp.einsum
+
+    def undefined_past_groups(lhs, rhs, group_sizes, **kw):
+        out = real_ragged(lhs, rhs, group_sizes, **kw)
+        past = jnp.arange(out.shape[0]) >= group_sizes.sum()
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    def undefined_off_routes(spec, *ops, **kw):
+        out = real_einsum(spec, *ops, **kw)
+        if spec == "etf,efd->etd":
+            out = jnp.where(unrouted[..., None], jnp.nan, out)
+        return out
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", undefined_past_groups)
+    monkeypatch.setattr(jnp, "einsum", undefined_off_routes)
+    y, routes = _through(path, x, lp, cfg)
     assert 0 < int(routes.sum()) < 2 * 24 * 2     # some rows in no group
+    assert bool(unrouted.any())
     want = ref._experts(x, lp, _conf(), "f32")
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
+
+
+def test_dense_path_at_a_decode_shape_with_an_idle_held_expert():
+    """32 decode rows whose router never picks held expert 1 (its column
+    scores every positive input far below the rest): the layer takes the
+    dense path, equals the reference, and counts the grouped path's
+    routes, none of them to expert 1."""
+    cfg = _cfg()
+    lp = jax.tree.map(lambda a: a[1], _params(cfg, seed=9)["layers"]["moe"])
+    lp = dict(lp, router=lp["router"].at[:, 1].set(-0.05))
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(10), (32, 1, 256), F32))
+    assert moe.dense_experts(32, cfg.moe.top_k, cfg.moe.num_experts, HELD)
+    y, routes = moe_served(x, lp, cfg)
+    _, grouped_routes = _through("grouped", x, lp, cfg)
+    assert routes[0] > 0 and routes[1] == 0, routes
+    assert routes.tolist() == grouped_routes.tolist()
+    want = ref._experts(x, lp, _conf(), "f32")
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
+
+
+def test_dense_path_when_every_route_goes_to_absent_experts():
+    """Held experts 6 and 7 under a router that sends every token to
+    experts 0 and 1: nothing is theirs to add.  The dense path gives the
+    reference's zeros and the grouped path's routes, even with NaN in
+    the held experts' weights."""
+    cfg, conf = _cfg(first=6), _conf(first=6)
+    lp = jax.tree.map(lambda a: a[0], _params(cfg, seed=11)["layers"]["moe"])
+    lp = dict(lp, router=jnp.zeros_like(lp["router"]))
+    x = jax.random.normal(jax.random.PRNGKey(12), (32, 1, 256), F32)
+    want = ref._experts(x, lp, conf, "f32")
+    assert not np.asarray(want).any()
+    y, routes = _through("dense", x, lp, cfg)
+    _, grouped_routes = _through("grouped", x, lp, cfg)
+    assert routes.tolist() == grouped_routes.tolist() == [0, 0]
+    np.testing.assert_array_equal(y, want)
+    poisoned = dict(lp, wo=jnp.full_like(lp["wo"], jnp.nan))
+    y, _ = _through("dense", x, poisoned, cfg)
+    np.testing.assert_array_equal(y, want)
+
+
+def _has_grouped_matmul(tokens, top_k=8, d=64):
+    """Whether ``moe_served`` lowers through ``ragged_dot`` for ``tokens``
+    tokens at Mellum's routing (16 of 64 experts held, top 8 or
+    ``top_k``)."""
+    c = get_config("mellum2-12b-a2.5b")
+    cfg = dataclasses.replace(c, d_model=d, moe=dataclasses.replace(
+        c.moe, d_ff_expert=32, held=16, top_k=top_k))
+    s = jax.ShapeDtypeStruct
+    p = {"router": s((d, 64), F32), "wi": s((16, d, 32), F32),
+         "wg": s((16, d, 32), F32), "wo": s((16, 32, d), F32)}
+    jaxpr = jax.make_jaxpr(lambda x, p: moe_served(x, p, cfg))(
+        s((tokens, 1, d), F32), p)
+    return "ragged_dot" in str(jaxpr)
+
+
+def test_the_shapes_choose_the_path():
+    """The cell's decode step of 32 rows and its longest prefill, 1,024
+    positions, compute the held experts densely; 2,048 positions (32,768
+    token-expert pairs), and 16 tokens routed once each (a quarter of a
+    route an expert), keep the grouped matmuls."""
+    assert not _has_grouped_matmul(32)
+    assert not _has_grouped_matmul(1024)
+    assert _has_grouped_matmul(2048)
+    assert _has_grouped_matmul(16, top_k=1)
 
 
 def test_yarn_frequencies_and_factor_match_transformers():

@@ -67,7 +67,7 @@ SERVED = textwrap.dedent("""
     from repro.configs.base import ModelConfig, MoEConfig
     from repro.models.sharding import ShardingRules, DEFAULT_RULES
     from repro.models import model as M
-    from repro.models.moe import moe_served
+    from repro.models.moe import dense_experts, moe_served
 
     # capacity factor 1: the training dispatch would drop routes here
     cfg = ModelConfig(name="t", family="moe", source="", num_layers=2,
@@ -79,20 +79,34 @@ SERVED = textwrap.dedent("""
     lp = jax.tree.map(lambda t: t[0], params["layers"])
     mesh = jax.make_mesh((2, 2), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32), jnp.float32)
-    y_one, r_one = jax.jit(lambda x, p: moe_served(x, p, cfg))(x, lp["moe"])
-    assert int(r_one.sum()) == 4 * 8 * 2
-    # batch sharded over data (prefill) and replicated (decode)
-    for batch_axis in ("data", None):
-        rules = ShardingRules(mesh, {**DEFAULT_RULES,
-                                     "act_batch": batch_axis})
-        with mesh:
-            y, r = jax.jit(lambda x, p: moe_served(x, p, cfg, rules))(
-                x, lp["moe"])
-        assert np.allclose(np.asarray(y_one), np.asarray(y), atol=2e-5), \\
-            (batch_axis, np.abs(np.asarray(y_one) - np.asarray(y)).max())
-        assert np.array_equal(np.asarray(r_one), np.asarray(r)), \\
-            (batch_axis, r_one, r)
+    # 8 positions a row: dense on one device and on every shard; 1100:
+    # grouped on one device (4 held x 4400 tokens), dense on the shards'
+    # 2 held; 2100: grouped on one device and on the replicated batch's
+    # shards (2 x 8400), dense on a data shard's 4200 tokens
+    paths = set()
+    for s in (8, 1100, 2100):
+        x = jax.random.normal(jax.random.PRNGKey(1), (4, s, 32), jnp.float32)
+        y_one, r_one = jax.jit(lambda x, p: moe_served(x, p, cfg))(
+            x, lp["moe"])
+        assert int(r_one.sum()) == 4 * s * 2
+        paths.add((s, "one", dense_experts(4 * s, 2, 4, 4)))
+        # batch sharded over data (prefill) and replicated (decode)
+        for batch_axis, shard_t in (("data", 2 * s), (None, 4 * s)):
+            paths.add((s, batch_axis, dense_experts(shard_t, 2, 4, 2)))
+            rules = ShardingRules(mesh, {**DEFAULT_RULES,
+                                         "act_batch": batch_axis})
+            with mesh:
+                y, r = jax.jit(lambda x, p: moe_served(x, p, cfg, rules))(
+                    x, lp["moe"])
+            assert np.allclose(np.asarray(y_one), np.asarray(y),
+                               atol=2e-5), \\
+                (s, batch_axis,
+                 np.abs(np.asarray(y_one) - np.asarray(y)).max())
+            assert np.array_equal(np.asarray(r_one), np.asarray(r)), \\
+                (s, batch_axis, r_one, r)
+    assert {(8, "one", True), (8, "data", True), (1100, "one", False),
+            (1100, None, True), (2100, None, False),
+            (2100, "data", True)} <= paths, paths
     # a layer holding a share of the experts is not split again
     held = dataclasses.replace(cfg, moe=dataclasses.replace(
         cfg.moe, held=2))

@@ -1,12 +1,14 @@
 """Ahead-of-time compiles for a described TPU v5e chip at Yi-6B's published
 widths: the served kernels, the on-device parameter draw, the prefill
-forward and the decode step.  Nothing runs — the chip's compiler refuses
+forward and the decode step; and Mellum2's served expert layer at its
+published widths.  Nothing runs — the chip's compiler refuses
 here what it would refuse on the chip (tiling, VMEM, HBM fit).
 
 The topology is described inside a module fixture, never at import, so
 every test worker collects the same tests and only the worker that runs
 this file loads the TPU compiler.
 """
+import dataclasses
 import functools
 import os
 import re
@@ -192,3 +194,60 @@ def test_block_write_updates_pools_in_place(one_chip, positions):
                                  jnp.int32, sharding=one_chip)
     compiled = E._write_blocks.lower(pool, pool, slots, k, k).compile()
     _assert_in_place(compiled, pool)
+
+
+def _stack_copies(compiled, dims) -> list:
+    """Instructions of the compiled program that copy or transpose a whole
+    bf16 array of ``dims`` (in any order): a copy or a transpose, or any
+    other instruction but a bitcast that lays it out in another order
+    than its shape's."""
+    out = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = bf16\[([\d,]+)\]\{([\d,]+)"
+                     r"\S* ([\w-]+)\(", line)
+        if not m or sorted(map(int, m.group(2).split(","))) != sorted(dims):
+            continue
+        name, layout, op = m.group(1), m.group(3).split(","), m.group(4)
+        reordered = layout != sorted(layout, reverse=True)
+        if (op in ("copy", "transpose")
+                or name.startswith(("copy", "transpose"))
+                or (reordered and op != "bitcast")):
+            out.append(line[:160])
+    return out
+
+
+def test_mellum_decode_computes_its_held_experts_densely(one_chip):
+    """Mellum2 at its published widths, one period of four layers, 16 of
+    64 experts held, as served in its batch cell: the decode step of 32
+    rows has no grouped matmul and copies or transposes no whole expert
+    stack; nor has a prefill of 1,024 positions, the cell's longest,
+    while one of 2,048 keeps the grouped matmuls."""
+    c = get_config("mellum2-12b-a2.5b")
+    cfg = dataclasses.replace(c, num_layers=4, moe=dataclasses.replace(
+        c.moe, held=16))
+    params = _sds(jax.eval_shape(functools.partial(M.init_params, cfg=cfg),
+                                 jax.random.PRNGKey(0)), one_chip)
+    b, n_slots = 32, 64
+    pool = jnp.zeros((cfg.num_layers, n_slots, chip_smoke.BLOCK_SIZE,
+                      cfg.num_kv_heads, cfg.resolved_head_dim), jnp.float32)
+    i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
+    state = jax.eval_shape(lambda: M.DecodeState(
+        tokens=i32((b,)), pos=i32((b,)),
+        kv=M.KVPools(pool_k=pool, pool_v=pool, slot_req=i32((n_slots,)),
+                     slot_base=i32((n_slots,)), append_slot=i32((b,)),
+                     append_off=i32((b,))),
+        peer=None, states=None))
+    decode = E._serve_step.lower(params, _sds(state, one_chip), cfg=cfg,
+                                 rules=None).compile()
+    text = decode.as_text()
+    assert "ragged-dot" not in text
+    stack = (16, cfg.d_model, cfg.moe.d_ff_expert)
+    assert not _stack_copies(decode, stack), _stack_copies(decode, stack)
+    for s in (1024, 2048):
+        batch = _sds({"tokens": jnp.zeros((1, s), jnp.int32),
+                      "positions": jnp.zeros((1, s), jnp.int32)}, one_chip)
+        prefill = E._forward.lower(params, batch, cfg=cfg, rules=None,
+                                   want_kv=True).compile()
+        assert ("ragged-dot" in prefill.as_text()) == (s > 1024), s
+        if s <= 1024:
+            assert not _stack_copies(prefill, stack)
